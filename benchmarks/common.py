@@ -1,10 +1,9 @@
 """Shared benchmark helpers: PI index drivers + timing + CSV output.
 
-Paper-fidelity note: sizes are scaled to this container (1 CPU core, no
-TPU): dataset sizes default to 2^14..2^18 instead of 2M..256M, and the
-reported metric is query throughput (queries/s), matching the paper's
-y-axes.  Trends (the paper's claims) are what we validate; absolute Xeon
-numbers are out of scope by construction.
+Paper-fidelity note: dataset sizes default to 2^14..2^18 instead of the
+paper's 2M..256M, and the reported metric is query throughput
+(queries/s), matching the paper's y-axes.  Every row names the device it
+ran on (``emit``); a number from a CPU run is not a TPU measurement.
 """
 from __future__ import annotations
 
@@ -34,13 +33,14 @@ def default_backend() -> str:
 def bench_backends():
     """Backends worth timing side by side on this host.
 
-    ``pallas`` (compiled Mosaic) only lowers on a real TPU; interpret mode
-    runs the identical grid computation everywhere.
+    On a TPU only ``xla``: the Pallas kernels do not compile for the TPU
+    today (DESIGN.md §3) and stay opt-in (``PI_BACKEND=pallas``).  The
+    interpreter runs the kernels' grid computation as plain JAX ops and is
+    offered only on the CPU, where it is the one way to run them at all.
     """
-    backends = ["xla", "pallas-interpret"]
     if jax.default_backend() == "tpu":
-        backends.append("pallas")
-    return backends
+        return ["xla"]
+    return ["xla", "pallas-interpret"]
 
 
 def make_index(n_keys: int, fanout: int = 8, seed: int = 0,
@@ -116,9 +116,10 @@ def run_query_stream(idx, ycfg, keys, n_batches: int, warmup: int = 2):
 def emit(rows, header, fig=None, config=None):
     """Print the CSV block and write ``BENCH_<fig>.json`` next to it.
 
-    The JSON side channel is what populates the perf trajectory across
-    PRs: rows + header verbatim, plus the engine backend and whatever
-    scenario config the figure wants recorded.  ``fig`` defaults to the
+    The JSON side channel records rows + header verbatim, plus the engine
+    backend, the device the rows were measured on (platform, kind and
+    count, as JAX reports them) and whatever scenario config the figure
+    wants recorded.  ``fig`` defaults to the
     first column of the first row (every figure script tags rows that
     way); ``BENCH_DIR`` overrides the output directory (default: cwd).
     """
@@ -132,6 +133,8 @@ def emit(rows, header, fig=None, config=None):
             "fig": fig,
             "backend": default_backend(),
             "jax_backend": jax.default_backend(),
+            "device_kind": jax.devices()[0].device_kind,
+            "device_count": len(jax.devices()),
             "timestamp": time.time(),
             "header": list(header),
             "rows": [list(r) for r in rows],

@@ -12,6 +12,7 @@ import subprocess
 import sys
 
 from benchmarks.common import emit
+from repro.compile_cache import use_compile_cache
 
 SCRIPT = r"""
 import json, time, numpy as np, jax, jax.numpy as jnp
@@ -25,28 +26,28 @@ theta, rebalance = {THETA}, {REB}
 cfg = PIConfig(capacity=2*N, pending_capacity=max(1024, N//8), fanout=8)
 ycfg = data_mod.YCSBConfig(n_keys=N, batch=8192, theta=theta)
 keys, vals = data_mod.ycsb_dataset(ycfg)
-state = build_sharded(cfg, S, keys, vals)
 mesh = jax.make_mesh((S,), ("data",))
+state = build_sharded(cfg, S, keys, vals, mesh=mesh)
 run, cap = make_sharded_executor(mesh, cfg, 8192 // S, capacity_factor=8.0)
 mk = lambda s: tuple(jnp.asarray(a) for a in data_mod.ycsb_batch(ycfg, keys, s))
 shards, fences = state.shards, state.fences
 loads = np.zeros(S)
 # observe + optionally rebalance
 for s in range(3):
-    shards, f, vv, load, drop = run(shards, fences, *mk(s))
+    shards, f, vv, load, _ = run(shards, fences, *mk(s))
     loads += np.asarray(load)
 if rebalance:
     f2 = rebalance_from_load(np.asarray(fences), loads, smoothing=1.0,
                              key_lo=int(keys.min()), key_hi=int(keys.max()))
     kk, vvv = collect_pairs(dataclasses.replace(state, shards=shards))
-    state = build_sharded(cfg, S, kk, vvv, fences=f2)
+    state = build_sharded(cfg, S, kk, vvv, fences=f2, mesh=mesh)
     shards, fences = state.shards, state.fences
 for ops, k, v in [mk(10)]:
-    shards, f, vv, load, drop = run(shards, fences, ops, k, v)
+    shards, f, vv, load, _ = run(shards, fences, ops, k, v)
 jax.block_until_ready(f)
 t0 = time.perf_counter(); loads = np.zeros(S)
 for s in range(11, 19):
-    shards, f, vv, load, drop = run(shards, fences, *mk(s))
+    shards, f, vv, load, _ = run(shards, fences, *mk(s))
     loads += np.asarray(load)
 jax.block_until_ready(f)
 dt = time.perf_counter() - t0
@@ -66,8 +67,9 @@ def main(n_keys=1 << 16, thetas=(0.0, 0.5, 0.9)):
                  SCRIPT.replace("{N}", str(n_keys)).replace("{THETA}", str(th)).replace("{REB}", str(reb))],
                 capture_output=True, text=True, env=env, timeout=900)
             if out.returncode != 0:
-                rows.append(("fig10", reb, th, "ERROR", out.stderr[-200:]))
-                continue
+                raise RuntimeError(
+                    f"fig10 (rebalance={reb}, theta={th}) failed:\n"
+                    f"{out.stderr[-2000:]}")
             r = json.loads(out.stdout.strip().splitlines()[-1])
             rows.append(("fig10", reb, th, round(r["qps"]),
                          round(r["imbalance"], 2)))
@@ -75,4 +77,5 @@ def main(n_keys=1 << 16, thetas=(0.0, 0.5, 0.9)):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 from benchmarks.common import emit, make_index
 from repro import data as data_mod
 from repro.core import range_agg
+from repro.compile_cache import use_compile_cache
 
 
 def main(sizes=(1 << 14, 1 << 16), grans=(1, 10, 100, 1000),
@@ -37,4 +38,5 @@ def main(sizes=(1 << 14, 1 << 16), grans=(1, 10, 100, 1000),
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -6,6 +6,7 @@ residency), insert < search, then flattens for large datasets.
 import dataclasses
 
 from benchmarks.common import emit, make_index, run_query_stream
+from repro.compile_cache import use_compile_cache
 
 
 def main(sizes=(1 << 14, 1 << 15, 1 << 16, 1 << 17, 1 << 18),
@@ -22,4 +23,5 @@ def main(sizes=(1 << 14, 1 << 15, 1 << 16, 1 << 17, 1 << 18),
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

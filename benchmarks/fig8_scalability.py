@@ -13,6 +13,7 @@ import subprocess
 import sys
 
 from benchmarks.common import emit
+from repro.compile_cache import use_compile_cache
 
 SCRIPT = r"""
 import json, time, numpy as np, jax, jax.numpy as jnp
@@ -25,18 +26,18 @@ N = {N}
 cfg = PIConfig(capacity=max(1024, 2*N//S), pending_capacity=max(1024, N//S//4), fanout=8)
 ycfg = data_mod.YCSBConfig(n_keys=N, batch=8192)
 keys, vals = data_mod.ycsb_dataset(ycfg)
-state = build_sharded(cfg, S, keys, vals)
 mesh = jax.make_mesh((S,), ("data",))
+state = build_sharded(cfg, S, keys, vals, mesh=mesh)
 run, cap = make_sharded_executor(mesh, cfg, 8192 // S)
 batches = [tuple(jnp.asarray(a) for a in data_mod.ycsb_batch(ycfg, keys, s)) for s in range(10)]
 shards, fences = state.shards, state.fences
 for ops, k, v in batches[:2]:
-    shards, f, vv, load, drop = run(shards, fences, ops, k, v)
+    shards, f, vv, load, _ = run(shards, fences, ops, k, v)
 jax.block_until_ready(f)
 t0 = time.perf_counter()
 loads = np.zeros(S)
 for ops, k, v in batches[2:]:
-    shards, f, vv, load, drop = run(shards, fences, ops, k, v)
+    shards, f, vv, load, _ = run(shards, fences, ops, k, v)
     loads += np.asarray(load)
 jax.block_until_ready(f)
 dt = time.perf_counter() - t0
@@ -55,12 +56,13 @@ def main(n_keys=1 << 16, shard_counts=(1, 2, 4, 8)):
              SCRIPT.replace("{S}", str(s)).replace("{N}", str(n_keys))],
             capture_output=True, text=True, env=env, timeout=600)
         if out.returncode != 0:
-            rows.append(("fig8", s, "ERROR", out.stderr[-200:]))
-            continue
+            raise RuntimeError(f"fig8 at {s} shards failed:\n"
+                               f"{out.stderr[-2000:]}")
         r = json.loads(out.stdout.strip().splitlines()[-1])
         rows.append(("fig8", s, round(r["qps"]), round(r["imbalance"], 3)))
     return emit(rows, ("fig", "shards", "qps", "load_imbalance"))
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -6,6 +6,7 @@ Paper claim: throughput decreases slightly as the insert share grows
 import dataclasses
 
 from benchmarks.common import emit, make_index, run_query_stream
+from repro.compile_cache import use_compile_cache
 
 
 def main(n_keys=1 << 16, ratios=(0.0, 0.2, 0.4, 0.6, 0.8, 1.0),
@@ -20,4 +21,5 @@ def main(n_keys=1 << 16, ratios=(0.0, 0.2, 0.4, 0.6, 0.8, 1.0),
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -43,6 +43,7 @@ from repro.pipeline import (ArrivalConfig, Collector, Dispatcher, Durability,
                             OverloadConfig, OverloadController,
                             PipelineMetrics, RetryPolicy, WindowConfig,
                             make_arrivals)
+from repro.compile_cache import use_compile_cache
 
 
 def replay(index, stream, wcfg: WindowConfig, depth: int, bulk: bool):
@@ -406,4 +407,5 @@ def main(n_keys=1 << 18, batch=8192, n_arrivals=1 << 16,
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -41,6 +41,7 @@ from repro.core import RANGE, range_agg
 from repro.pipeline import (ArrivalConfig, Collector, Dispatcher,
                             PipelineMetrics, WindowConfig, make_arrivals,
                             range_trace_count)
+from repro.compile_cache import use_compile_cache
 
 
 MAX_SPAN = 2048
@@ -153,4 +154,5 @@ def main(n_keys=1 << 15, batch=256, n_arrivals=4096):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -35,6 +35,7 @@ import numpy as np
 from benchmarks.common import default_backend, emit
 from repro.core import PIConfig, build, insert_batch, live_items, rebuild
 from repro.core import index as pi_index
+from repro.compile_cache import use_compile_cache
 
 _repack = pi_index.repack
 
@@ -119,4 +120,5 @@ def main(n_keys: int = 1 << 17, fanout: int = 4,
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -10,6 +10,7 @@ we compare the analytic byte count against instrumented traversal
 import math
 
 from benchmarks.common import emit, make_index
+from repro.compile_cache import use_compile_cache
 
 
 def main(sizes=(1 << 14, 1 << 16, 1 << 18), fanout=8):
@@ -31,4 +32,5 @@ def main(sizes=(1 << 14, 1 << 16, 1 << 18), fanout=8):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

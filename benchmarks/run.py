@@ -10,6 +10,7 @@ from benchmarks import (fig6_dataset_size, fig7_batch_size, fig8_scalability,
                         fig9_mixed, fig10_skew, fig14_range, fig15_breakdown,
                         fig_pipeline, fig_range_pipeline, fig_rebuild,
                         model_check)
+from repro.compile_cache import use_compile_cache
 
 # every figure's emit() also writes a machine-readable BENCH_<fig>.json
 # (rows + backend + scenario config) into BENCH_DIR (default: cwd) — that
@@ -39,4 +40,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -13,7 +13,8 @@ from repro.core.engine import BACKENDS, Probe, SearchEngine, get_engine
 from repro.core.index import (
     PIConfig, PIIndex, build, empty, execute, execute_impl,
     execute_trace_count, incremental_fits, live_items, lookup, traverse,
-    rebuild, maybe_rebuild, needs_rebuild, range_agg, repack, search_batch,
+    rebuild, maybe_rebuild, needs_rebuild, range_agg, rebuild_if_due, repack,
+    search_batch,
     insert_batch, delete_batch, validate_layout, with_backend,
 )
 from repro.core.distributed import (
@@ -31,7 +32,8 @@ __all__ = [
     "empty",
     "execute", "execute_impl", "execute_trace_count", "incremental_fits",
     "live_items", "lookup", "traverse",
-    "rebuild", "maybe_rebuild", "needs_rebuild", "range_agg", "repack",
+    "rebuild", "maybe_rebuild", "needs_rebuild", "range_agg",
+    "rebuild_if_due", "repack",
     "search_batch",
     "insert_batch", "delete_batch", "validate_layout", "with_backend",
     "SearchEngine", "get_engine", "Probe", "BACKENDS",

@@ -7,7 +7,9 @@ to the owning node and processed entirely in local memory.
 TPU mapping (DESIGN.md §2):
 
 * NUMA node        → mesh shard along the ``data`` axis
-* per-node index   → one ``PIIndex`` per shard (stacked-leaf pytree)
+* per-node index   → one ``PIIndex`` per shard (stacked-leaf pytree whose
+                     leading dim is sharded ``P("data")``: shard s lives on
+                     the s-th device of the axis)
 * query routing    → bucketize by fence keys + ``jax.lax.all_to_all``
 * QPI hop          → one ICI all_to_all each way (the *only* cross-shard
                      traffic; execution itself is collective-free, which is
@@ -16,16 +18,19 @@ TPU mapping (DESIGN.md §2):
                      (``core.rebalance``) — TPUs cannot move cores between
                      shards, so we move the *range boundaries* instead.
 
+Every program that touches the stacked shard leaves runs them inside
+``jax.shard_map``: each device sees only its own shard(s), so nothing is
+gathered onto one chip.  Host-side readers (``collect_pairs``) fetch the
+leaves with an explicit ``jax.device_get``.
+
 The dispatch machinery (sort by destination, capacity-bounded send buffers,
 all_to_all, inverse routing) is deliberately the same shape as an MoE
-token dispatch; ``models/moe.py`` reuses it — the paper's technique as a
-first-class framework feature.
+token dispatch; ``models/moe.py`` reuses it.
 """
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
-from typing import Tuple
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -35,9 +40,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import index as pi
 from repro.core.batch import SEARCH
 from repro.core.engine import sentinel_for
-from repro.sharding import shard_map
 
-NOOP_KEY = None  # padding queries use the key-dtype sentinel (max value)
+AXIS = "data"  # the mesh axis the shards are laid out along
 
 
 # ---------------------------------------------------------------------------
@@ -94,7 +98,7 @@ def scatter_to_buffer(arr: jnp.ndarray, order: jnp.ndarray, slot: jnp.ndarray,
 class ShardedPIIndex:
     """Stacked per-shard PIIndex + replicated fence keys.
 
-    ``shards`` leaves have leading dim S (the data-axis size); ``fences``
+    ``shards`` leaves have leading dim S (the shard count); ``fences``
     has S+1 entries with fences[0] = dtype.min and fences[S] = sentinel.
     Shard s owns keys in [fences[s], fences[s+1]).
     """
@@ -103,13 +107,18 @@ class ShardedPIIndex:
     fences: jnp.ndarray         # (S+1,)
     n_shards: int
 
-    def live_count(self):
-        return jax.vmap(lambda s: s.live_count)(self.shards)
-
 
 def build_sharded(cfg: pi.PIConfig, n_shards: int, keys, vals,
-                  fences=None) -> ShardedPIIndex:
-    """Host-side build: partition by fences (default: equi-depth) and stack."""
+                  fences=None, *, mesh: Mesh) -> ShardedPIIndex:
+    """Host-side build: partition by fences (default: equi-depth), stack,
+    and place the stacked leaves ``P(AXIS)`` on ``mesh``.
+
+    Each device's block of shards goes straight from the host to that
+    device, so shard s of an S-way axis lives on its s-th device; the
+    fences are replicated.  ``n_shards`` must be a multiple of the axis
+    size (routing needs exactly one shard per device; RANGE and rebuild
+    also take several).
+    """
     keys = np.asarray(keys)
     vals = np.asarray(vals)
     order = np.argsort(keys)
@@ -129,17 +138,46 @@ def build_sharded(cfg: pi.PIConfig, n_shards: int, keys, vals,
             else (keys >= fences[s])
         shard_trees.append(pi.build(cfg, jnp.asarray(keys[m]),
                                     jnp.asarray(vals[m])))
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *shard_trees)
-    return ShardedPIIndex(shards=stacked, fences=jnp.asarray(fences),
-                          n_shards=n_shards)
+    if n_shards % mesh.shape[AXIS]:
+        raise ValueError(f"{n_shards} shards do not divide over the "
+                         f"{mesh.shape[AXIS]}-way {AXIS!r} axis")
+    host = jax.tree.map(lambda *xs: np.stack(xs),
+                        *jax.device_get(shard_trees))
+    stacked = jax.device_put(host, NamedSharding(mesh, P(AXIS)))
+    return ShardedPIIndex(
+        shards=stacked,
+        fences=jax.device_put(fences, NamedSharding(mesh, P())),
+        n_shards=n_shards)
 
 
 # ---------------------------------------------------------------------------
-# the shard-local body (runs under shard_map)
+# the shard-local bodies (run under shard_map)
 # ---------------------------------------------------------------------------
+
+@jax.jit
+def maybe_rebuild_shards(shards: pi.PIIndex):
+    """Per-shard rebuild daemon over stacked shard leaves.
+
+    Each shard rebuilds iff *it* is due (``pi.rebuild_if_due``): a not-due
+    shard's pending churn stays buffered for its own later — likely
+    incremental — rebuild instead of being force-repacked whenever a
+    sibling trips the threshold.  ``lax.map`` keeps every shard's cond a
+    real branch (under ``vmap`` it would lower to a select, so every shard
+    would pay both rebuild tiers).  Returns ``(shards, overflow, due,
+    incremental)``, the flags one per shard; ``overflow`` is snapshot
+    *before* the rebuild resets it on the state (overflow is data loss
+    and must stay observable).
+    """
+    def one(shard):
+        ovf = shard.overflow
+        shard, due, incr = pi.rebuild_if_due(shard)
+        return shard, ovf, due, incr
+
+    return jax.lax.map(one, shards)
+
 
 def _local_execute(shard: pi.PIIndex, fences, ops, qkeys, qvals,
-                   axis_name: str, cap: int, n_shards: int):
+                   cap: int, n_shards: int):
     """Route → execute → route back, from one shard's perspective.
 
     ``shard`` leaves arrive with a leading (1,) block dim from shard_map;
@@ -170,9 +208,9 @@ def _local_execute(shard: pi.PIIndex, fences, ops, qkeys, qvals,
     src_pos = jnp.full((S * cap,), -1, jnp.int32).at[slot].set(
         order.astype(jnp.int32), mode="drop").reshape(S, cap)
 
-    recv_ops = jax.lax.all_to_all(send_ops, axis_name, 0, 0, tiled=False)
-    recv_keys = jax.lax.all_to_all(send_keys, axis_name, 0, 0, tiled=False)
-    recv_vals = jax.lax.all_to_all(send_vals, axis_name, 0, 0, tiled=False)
+    recv_ops = jax.lax.all_to_all(send_ops, AXIS, 0, 0, tiled=False)
+    recv_keys = jax.lax.all_to_all(send_keys, AXIS, 0, 0, tiled=False)
+    recv_vals = jax.lax.all_to_all(send_vals, AXIS, 0, 0, tiled=False)
 
     # --- local execution (collective-free: the paper's "no remote access")
     flat = lambda x: x.reshape((S * cap,) + x.shape[2:])
@@ -180,8 +218,8 @@ def _local_execute(shard: pi.PIIndex, fences, ops, qkeys, qvals,
         local, flat(recv_ops), flat(recv_keys), flat(recv_vals))
 
     # --- inbound routing of results ---------------------------------------
-    rf = jax.lax.all_to_all(r_found.reshape(S, cap), axis_name, 0, 0)
-    rv = jax.lax.all_to_all(r_val.reshape(S, cap), axis_name, 0, 0)
+    rf = jax.lax.all_to_all(r_found.reshape(S, cap), AXIS, 0, 0)
+    rv = jax.lax.all_to_all(r_val.reshape(S, cap), AXIS, 0, 0)
     src = src_pos.reshape(S * cap)
     tgt = jnp.where(src >= 0, src, b)
     out_found = jnp.zeros((b,), bool).at[tgt].set(rf.reshape(-1), mode="drop")
@@ -193,60 +231,59 @@ def _local_execute(shard: pi.PIIndex, fences, ops, qkeys, qvals,
     return new_shard, out_found, out_val, load[None], n_drop[None]
 
 
-# jitted executors are memoized: re-jitting the shard_map body on every
-# batch was the dominant dispatch cost (and defeated XLA's compile cache
-# for the Pallas probe kernel inside pi.execute_impl).
-_EXECUTOR_CACHE: dict = {}
-
-
+@lru_cache(maxsize=None)
 def make_sharded_executor(mesh: Mesh, cfg: pi.PIConfig, batch_per_shard: int,
-                          axis_name: str = "data",
                           capacity_factor: float = 2.0):
     """Build (or fetch) the jitted shard_map'd batch executor for a mesh.
 
-    Memoized by ``(mesh, cfg, batch_per_shard, axis_name, capacity_factor)``
-    — note ``cfg`` includes the search backend, so ``xla`` and ``pallas``
-    executors coexist in the cache.  Returns ``fn(state, ops, keys, vals)
-    -> (state', found, vals, load, dropped)`` where ops/keys/vals are
-    global arrays of shape (S * batch_per_shard,) sharded along
-    ``axis_name``.
+    Memoized by ``(mesh, cfg, batch_per_shard, capacity_factor)`` —
+    re-jitting the shard_map body on every batch was the dominant dispatch
+    cost.  ``cfg`` includes the search backend, so ``xla`` and ``pallas``
+    executors coexist in the cache.  Returns ``(fn, cap)`` with
+    ``fn(state, fences, ops, keys, vals) -> (state', found, vals, load,
+    dropped)``: ops/keys/vals are global (S * batch_per_shard,) arrays
+    sharded along ``AXIS``; ``load`` and ``dropped`` are (S,) per-shard
+    counts of the queries each shard received and of the real queries
+    routing lost.  It routes and executes only; the rebuild daemon is
+    ``maybe_rebuild_sharded``.
     """
-    cache_key = (mesh, cfg, batch_per_shard, axis_name, capacity_factor)
-    cached = _EXECUTOR_CACHE.get(cache_key)
-    if cached is not None:
-        return cached
-    S = mesh.shape[axis_name]
+    S = mesh.shape[AXIS]
     # integer-exact ceil (PI004): the factor is frozen to a /1024 rational
     # so the lane budget cannot wobble with float rounding — the same
     # split needs_rebuild uses for its churn threshold
     num = int(round(capacity_factor * 1024))
     cap = -(-batch_per_shard * num // (S * 1024))
-    spec_state = jax.tree.map(lambda _: P(axis_name), pi.empty(cfg))
-    # fences replicated; batch sharded on arrival
-    body = partial(_local_execute, axis_name=axis_name, cap=cap, n_shards=S)
-    mapped = shard_map(
+    body = partial(_local_execute, cap=cap, n_shards=S)
+    # state and batch sharded along the axis, fences replicated
+    mapped = jax.shard_map(
         body, mesh=mesh,
-        in_specs=(spec_state, P(), P(axis_name), P(axis_name), P(axis_name)),
-        out_specs=(spec_state, P(axis_name), P(axis_name), P(axis_name),
-                   P(axis_name)),
-        check_vma=False)
+        in_specs=(P(AXIS), P(), P(AXIS), P(AXIS), P(AXIS)),
+        out_specs=(P(AXIS),) * 5, check_vma=False)
 
     @jax.jit
-    def run(state_shards, fences, ops, qkeys, qvals):
+    def sharded_execute(state_shards, fences, ops, qkeys, qvals):
         return mapped(state_shards, fences, ops, qkeys, qvals)
 
-    _EXECUTOR_CACHE[cache_key] = (run, cap)
-    return run, cap
+    return sharded_execute, cap
 
 
 def execute_sharded(state: ShardedPIIndex, mesh: Mesh, ops, qkeys, qvals,
-                    axis_name: str = "data", capacity_factor: float = 2.0):
-    """Convenience one-shot wrapper (executor fetched from the memo cache)."""
+                    capacity_factor: float = 2.0):
+    """One routed window → ``(state', (found, val), load, dropped)``.
+
+    The executor is fetched from the memo cache; see
+    ``make_sharded_executor`` for ``load`` and ``dropped``.  Needs one
+    shard per device of the axis.
+    """
     B = ops.shape[0]
     S = state.n_shards
-    assert B % S == 0, "global batch must divide the shard count"
+    if S != mesh.shape[AXIS]:
+        raise ValueError(f"{S} shards on a {mesh.shape[AXIS]}-way {AXIS!r} "
+                         f"axis: routing needs one per device")
+    if B % S:
+        raise ValueError(f"global batch {B} must divide the shard count {S}")
     run, _ = make_sharded_executor(
-        mesh, state.shards.config, B // S, axis_name, capacity_factor)
+        mesh, state.shards.config, B // S, capacity_factor)
     shards, found, val, load, dropped = run(
         state.shards, state.fences, ops, qkeys, qvals)
     new_state = ShardedPIIndex(shards=shards, fences=state.fences,
@@ -254,47 +291,56 @@ def execute_sharded(state: ShardedPIIndex, mesh: Mesh, ops, qkeys, qvals,
     return new_state, (found, val), load, dropped
 
 
-def rebuild_sharded(state: ShardedPIIndex) -> ShardedPIIndex:
-    """Per-shard deferred rebuild — embarrassingly parallel (paper §4.1)."""
-    shards = jax.vmap(pi.rebuild)(state.shards)
-    return ShardedPIIndex(shards=shards, fences=state.fences,
-                          n_shards=state.n_shards)
+@lru_cache(maxsize=None)
+def _sharded_maybe_rebuild(mesh: Mesh):
+    def body(shards):
+        pn = shards.pn  # fill high-water, before the rebuild empties it
+        shards, ovf, due, incr = maybe_rebuild_shards(shards)
+        return shards, dict(overflow=ovf, rebuilt=due, incremental=incr,
+                            pn=pn)
+
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=P(AXIS),
+                           out_specs=P(AXIS), check_vma=False)
+
+    @jax.jit
+    def sharded_maybe_rebuild(shards):
+        shards, st = mapped(shards)
+        rebuilt = jnp.any(st["rebuilt"])
+        return shards, dict(
+            overflow=jnp.any(st["overflow"]),
+            rebuilt=rebuilt,
+            incremental=rebuilt & jnp.all(st["incremental"] | ~st["rebuilt"]),
+            pn=jnp.max(st["pn"]))
+
+    return sharded_maybe_rebuild
 
 
-@jax.jit
-def maybe_rebuild_shards(shards: pi.PIIndex):
-    """Per-shard dirty-tracked daemon on stacked shard leaves.
+def maybe_rebuild_sharded(state: ShardedPIIndex, mesh: Mesh):
+    """Rebuild daemon on a mesh → ``(state', flags)``.
 
-    A single cond gates the whole sweep (no dispatch when nothing is
-    due), but inside it each shard keeps its own state unless *it* is
-    due: a not-due shard's pending churn stays buffered for its own later
-    — likely incremental — rebuild instead of being force-repacked
-    whenever a sibling trips the threshold.  (Under vmap the inner
-    two-tier ``pi.rebuild`` cond lowers to a select, so every shard pays
-    one rebuild's FLOPs during a sweep; the win is that *sweeps* are per
-    -shard-due now, not all-or-none, and each shard's rebuild is
-    churn-proportional.)  Returns ``(shards, any_overflow, any_due)`` —
-    the overflow flag is snapshot *before* the rebuild resets it on the
-    state (overflow is data loss and must stay observable).
+    Each device runs ``maybe_rebuild_shards`` over the shard(s) it holds.
+    ``flags`` are scalars reduced over the shards: ``overflow`` (any
+    pending overflow, before the rebuild resets it), ``rebuilt`` (any
+    shard rebuilt), ``incremental`` (every rebuilt shard took the
+    incremental tier) and ``pn`` (the hottest shard's pending fill
+    high-water, before the rebuild).
     """
-    ovf = jnp.any(shards.overflow)
-    due_each = jax.vmap(pi.needs_rebuild)(shards)
-    due = jnp.any(due_each)
-
-    def sweep(s):
-        rebuilt = jax.vmap(pi.rebuild)(s)
-        def sel(a, b):
-            m = due_each.reshape((-1,) + (1,) * (a.ndim - 1))
-            return jnp.where(m, a, b)
-        return jax.tree.map(sel, rebuilt, s)
-
-    shards = jax.lax.cond(due, sweep, lambda s: s, shards)
-    return shards, ovf, due
+    shards, flags = _sharded_maybe_rebuild(mesh)(state.shards)
+    return ShardedPIIndex(shards=shards, fences=state.fences,
+                          n_shards=state.n_shards), flags
 
 
-def maybe_rebuild_sharded(state: ShardedPIIndex) -> ShardedPIIndex:
-    """State-level wrapper of ``maybe_rebuild_shards``."""
-    shards, _, _ = maybe_rebuild_shards(state.shards)
+@lru_cache(maxsize=None)
+def _sharded_rebuild(mesh: Mesh):
+    return jax.jit(jax.shard_map(
+        partial(jax.lax.map, pi.rebuild), mesh=mesh,
+        in_specs=P(AXIS), out_specs=P(AXIS), check_vma=False))
+
+
+def rebuild_sharded(state: ShardedPIIndex, mesh: Mesh) -> ShardedPIIndex:
+    """Forced per-shard rebuild — embarrassingly parallel (paper §4.1):
+    each device rebuilds only the shard(s) it holds."""
+    shards = _sharded_rebuild(mesh)(state.shards)
     return ShardedPIIndex(shards=shards, fences=state.fences,
                           n_shards=state.n_shards)
 
@@ -302,13 +348,14 @@ def maybe_rebuild_sharded(state: ShardedPIIndex) -> ShardedPIIndex:
 def collect_pairs(state: ShardedPIIndex):
     """Host-side: pull all live (key, val) pairs (for resharding/tests).
 
-    Occupancy is ``key != sentinel`` per slot — the segmented gapped
-    storage has no dense ``[:n]`` prefix to slice.
+    One explicit ``device_get`` of the stacked leaves, then a per-shard
+    occupancy scan (``key != sentinel`` — the segmented gapped storage has
+    no dense ``[:n]`` prefix to slice).
     """
+    host = jax.device_get(state.shards)
     ks, vs = [], []
     for s in range(state.n_shards):
-        shard = jax.tree.map(lambda x: x[s], state.shards)
-        k, v = pi.live_items(shard)
+        k, v = pi.live_items(jax.tree.map(lambda x: x[s], host))
         ks.append(k)
         vs.append(v)
     k = np.concatenate(ks)

@@ -57,7 +57,6 @@ from repro.core.engine import BACKENDS, get_engine, sentinel_for
 
 KSENT_I32 = jnp.iinfo(jnp.int32).max  # padding key: sorts after every real key
 
-# historical alias (distributed.py and older call sites use pi._sentinel)
 _sentinel = sentinel_for
 
 
@@ -679,9 +678,27 @@ def rebuild(index: PIIndex) -> PIIndex:
         _rebuild_incremental, _rebuild_repack, index)
 
 
+def rebuild_if_due(index: PIIndex):
+    """The rebuild daemon's step: rebuild iff ``needs_rebuild``.
+
+    Returns ``(index', due, incremental)``: ``incremental`` reports which
+    tier a due rebuild took (the segmented merge vs the full repack) so
+    callers can attribute rebuild cost to churn, not capacity.  The tier
+    probe lives inside the due-branch, so a window that does not rebuild
+    pays nothing for it.
+    """
+    due = needs_rebuild(index)
+    index, incr = jax.lax.cond(
+        due,
+        lambda i: (rebuild(i), incremental_fits(i) & ~i.overflow),
+        lambda i: (i, jnp.array(False)),
+        index)
+    return index, due, incr
+
+
 def maybe_rebuild(index: PIIndex) -> PIIndex:
     """Branchless 'daemon': rebuild iff the update threshold tripped."""
-    return jax.lax.cond(needs_rebuild(index), rebuild, lambda i: i, index)
+    return rebuild_if_due(index)[0]
 
 
 # Sanctioned forced-repack entry (PI001): the breaker's reclaim path and

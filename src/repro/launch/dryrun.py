@@ -126,8 +126,6 @@ def lower_cell(arch: str, shape: str, mesh_kind: str,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax 0.4.x: one dict per program
-        cost = cost[0] if cost else {}
     n_dev = int(np.prod(mesh.devices.shape))
 
     result = {

@@ -197,7 +197,7 @@ def moe_apply_shardmap(cfg: ModelConfig, p: Dict, x, capacity_factor=None):
         out = jnp.zeros((N_loc, d), xf_l.dtype).at[tok_of].add(contrib)
         return jax.lax.psum(out, model_ax)
 
-    out = shd.shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec), P(bspec), P(bspec),
                   P(model_ax), P(model_ax), P(model_ax)),

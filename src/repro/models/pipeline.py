@@ -28,7 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.sharding import shard_map
 
 
 def pipelined_forward(mesh: Mesh, axis: str, stage_fn: Callable,
@@ -83,7 +82,7 @@ def pipelined_forward(mesh: Mesh, axis: str, stage_fn: Callable,
         return jax.lax.psum(contrib, axis)
 
     specs_p = jax.tree.map(lambda _: P(axis), stage_params)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(specs_p, P()), out_specs=P(),
         check_vma=False)(stage_params, x_microbatches)

@@ -94,21 +94,12 @@ def _step_single(index, ops, keys, vals):
     execution — the exact overlap double-buffering exists to create.  The
     price is one transient extra copy of the index state in memory.
 
-    ``incr`` reports which tier a due rebuild took (the segmented
-    incremental merge vs the full repack) so the pipeline metrics can
-    attribute rebuild cost to churn, not capacity.  The tier probe lives
-    inside the due-branch so windows that don't rebuild (the vast
-    majority) pay nothing for it.
+    ``incr`` reports which tier a due rebuild took (``pi.rebuild_if_due``).
     """
     new_index, (found, val) = pi.execute_impl(index, ops, keys, vals)
     ovf = new_index.overflow
     pn = new_index.pn  # fill high-water: post-rebuild pn is ~always zero
-    due = pi.needs_rebuild(new_index)
-    new_index, incr = jax.lax.cond(
-        due,
-        lambda i: (pi.rebuild(i), pi.incremental_fits(i) & ~i.overflow),
-        lambda i: (i, jnp.array(False)),
-        new_index)
+    new_index, due, incr = pi.rebuild_if_due(new_index)
     return new_index, found, val, ovf, due, incr, pn
 
 
@@ -185,7 +176,7 @@ class _InFlight:
     val: jnp.ndarray
     overflow: jnp.ndarray  # snapshot scalar, taken before the rebuild reset
     rebuilt: jnp.ndarray
-    incr: Optional[jnp.ndarray]     # rebuild tier taken (None: sharded path)
+    incr: Optional[jnp.ndarray]     # rebuild tier taken (None: breaker replay)
     dropped: Optional[jnp.ndarray]  # sharded routing drops (None: local)
     pn: Optional[jnp.ndarray] = None  # pending fill high-water (pre-rebuild)
     rcnt: Optional[jnp.ndarray] = None  # RANGE counts (pre-window state)
@@ -300,17 +291,14 @@ class Dispatcher:
             state, (found, val), _, dropped = dist.execute_sharded(
                 self._index, self._mesh, ops, keys, vals,
                 capacity_factor=self.capacity_factor)
-            pn = jnp.max(state.shards.pn)  # hottest shard's fill high-water
-            shards, ovf, rebuilt = dist.maybe_rebuild_shards(state.shards)
-            self._index = dist.ShardedPIIndex(
-                shards=shards, fences=state.fences, n_shards=state.n_shards)
-            incr = None
-            dropped = jnp.sum(dropped)
-        else:
-            self._index, found, val, ovf, rebuilt, incr, pn = _step_single(
-                self._index, ops, keys, vals)
-            dropped = None
-        return found, val, ovf, rebuilt, incr, dropped, pn
+            # each device rebuilds its own shard iff it is due; the flags
+            # arrive reduced over the shards
+            self._index, f = dist.maybe_rebuild_sharded(state, self._mesh)
+            return (found, val, f["overflow"], f["rebuilt"],
+                    f["incremental"], jnp.sum(dropped), f["pn"])
+        self._index, found, val, ovf, rebuilt, incr, pn = _step_single(
+            self._index, ops, keys, vals)
+        return found, val, ovf, rebuilt, incr, None, pn
 
     def _window_has_writes(self, window: Window) -> bool:
         occ = window.occupancy
@@ -346,8 +334,8 @@ class Dispatcher:
         keys = jnp.asarray(window.keys)
         keys2 = jnp.asarray(window.keys2)
         if isinstance(self._index, dist.ShardedPIIndex):
-            return execute_ranges_sharded(self._index, ops, keys, keys2,
-                                          self.max_span)
+            return execute_ranges_sharded(self._index, self._mesh, ops, keys,
+                                          keys2, self.max_span)
         return execute_ranges(self._index, ops, keys, keys2, self.max_span)
 
     def _breaker_armed(self) -> bool:

@@ -25,19 +25,21 @@ as point ops (DESIGN.md §9):
   window batch and exactly one compiled range execute serves a run.
 * **sharding** — a range spanning several shards fans out per-shard
   clipped subranges ``[max(lo, fence_s), min(hi, fence_{s+1} - 1)]`` and
-  reduces the ``(count, sum)`` partials; shards own disjoint key
-  intervals, so the reduction is exact (no double counting).  Read-only,
-  so no ``all_to_all`` — every shard sees every query lane.
+  reduces the ``(count, sum)`` partials with one ``psum``; shards own
+  disjoint key intervals, so the reduction is exact (no double counting).
+  Read-only, so no ``all_to_all`` — every shard sees every query lane.
 """
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.analysis.runtime import trace_guard
 from repro.core.batch import RANGE
+from repro.core.distributed import AXIS
 from repro.core.engine import get_engine, sentinel_for
 
 # Bumped on every *trace* of the range executors (Python side effects run
@@ -81,7 +83,7 @@ def execute_ranges(index, ops: jnp.ndarray, keys: jnp.ndarray,
     return get_engine(index.config).range_agg(index, lo, hi, max_span)
 
 
-def execute_ranges_sharded(state, ops: jnp.ndarray, keys: jnp.ndarray,
+def execute_ranges_sharded(state, mesh, ops: jnp.ndarray, keys: jnp.ndarray,
                            keys2: jnp.ndarray, max_span: int):
     """Sharded fan-out/reduce: per-shard subranges, summed partials.
 
@@ -89,30 +91,43 @@ def execute_ranges_sharded(state, ops: jnp.ndarray, keys: jnp.ndarray,
     subrange is the query clipped to that interval — empty (lo > hi,
     hence inert) when the range misses the shard — and the global
     ``(count, sum)`` is the sum of partials over disjoint intervals.
-    The shard loop is unrolled inside one jitted program (S is static),
-    keeping the one-compile contract; ``max_span`` is a *per-shard*
-    budget here, so splitting can only widen what a span cap would
-    truncate, never narrow it.  ``state`` is a ``ShardedPIIndex`` (not a
-    pytree — its leaves are unpacked before the jit boundary).
+    The partials are computed inside ``shard_map``, each device over the
+    shard(s) it holds, and reduced with one ``psum``: one jitted program
+    per (mesh, geometry), keeping the one-compile contract.  ``max_span``
+    is a *per-shard* budget here, so splitting can only widen what a
+    span cap would truncate, never narrow it.  ``state`` is a
+    ``ShardedPIIndex`` (not a pytree — its leaves are unpacked before the
+    jit boundary).
     """
-    return _execute_ranges_sharded(state.shards, state.fences, ops, keys,
-                                   keys2, max_span, state.n_shards)
+    fn = _sharded_range_executor(mesh, max_span)
+    return fn(state.shards, state.fences, ops, keys, keys2)
 
 
-@partial(jax.jit, static_argnums=(5, 6))
-def _execute_ranges_sharded(shards, fences, ops, keys, keys2,
-                            max_span: int, n_shards: int):
-    _TRACES.bump()
-    kdt = shards.keys.dtype
-    lo, hi = _range_lanes(ops, keys, keys2, kdt)
-    cnt = jnp.zeros(ops.shape, jnp.int32)
-    sm = jnp.zeros(ops.shape, jnp.int32)
-    for s in range(n_shards):
-        shard = jax.tree_util.tree_map(lambda l: l[s], shards)
-        slo = jnp.maximum(lo, fences[s].astype(kdt))
-        shi = jnp.minimum(hi, (fences[s + 1] - 1).astype(kdt))
-        pc, ps = get_engine(shard.config).range_agg(shard, slo, shi,
-                                                    max_span)
-        cnt = cnt + pc
-        sm = sm + ps
-    return cnt, sm
+@lru_cache(maxsize=None)
+def _sharded_range_executor(mesh, max_span: int):
+    def body(shards, fences, lo, hi):
+        k = shards.keys.shape[0]          # shards held by this device
+        kdt = shards.keys.dtype
+        sid = jax.lax.axis_index(AXIS) * k + jnp.arange(k)
+        f_lo = fences[sid].astype(kdt)
+        f_hi = (fences[sid + 1] - 1).astype(kdt)
+
+        def partial_agg(shard, a, b):
+            return get_engine(shard.config).range_agg(
+                shard, jnp.maximum(lo, a), jnp.minimum(hi, b), max_span)
+
+        cnt, sm = jax.vmap(partial_agg)(shards, f_lo, f_hi)
+        return (jax.lax.psum(jnp.sum(cnt, 0), AXIS),
+                jax.lax.psum(jnp.sum(sm, 0), AXIS))
+
+    mapped = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(AXIS), P(), P(), P()),
+                           out_specs=(P(), P()), check_vma=False)
+
+    @jax.jit
+    def sharded_ranges(shards, fences, ops, keys, keys2):
+        _TRACES.bump()
+        lo, hi = _range_lanes(ops, keys, keys2, shards.keys.dtype)
+        return mapped(shards, fences, lo, hi)
+
+    return sharded_ranges

@@ -55,13 +55,10 @@ def _snapshot_tree(index):
     return index
 
 
-def _empty_tree(cfg: pi.PIConfig, kind: str, n_shards: int):
-    if kind == "sharded":
-        kdt = np.dtype(cfg.key_dtype)
-        state = dist.build_sharded(cfg, n_shards, np.zeros((0,), kdt),
-                                   np.zeros((0,), np.int32))
-        return (state.shards, state.fences)
-    return pi.empty(cfg)
+def _empty_sharded(cfg: pi.PIConfig, n_shards: int, mesh):
+    kdt = np.dtype(cfg.key_dtype)
+    return dist.build_sharded(cfg, n_shards, np.zeros((0,), kdt),
+                              np.zeros((0,), np.int32), mesh=mesh)
 
 
 class Durability:
@@ -234,15 +231,18 @@ def recover(directory: str, *, mesh=None, metrics=None, overload=None
             f"no complete snapshot under {directory}/ckpt — the initial "
             f"blocking snapshot never finished, so nothing was ever "
             f"acknowledged")
-    tree = ckpt.restore(step, _empty_tree(cfg, kind, n_shards))
     if kind == "sharded":
-        shards, fences = tree
-        index = dist.ShardedPIIndex(shards=shards, fences=fences,
-                                    n_shards=n_shards)
         if mesh is None:
             mesh = jax.make_mesh((n_shards,), ("data",))
+        # each shard's block goes from disk straight to its own device
+        target = _snapshot_tree(_empty_sharded(cfg, n_shards, mesh))
+        shards, fences = ckpt.restore(
+            step, target,
+            shardings=jax.tree.map(lambda x: x.sharding, target))
+        index = dist.ShardedPIIndex(shards=shards, fences=fences,
+                                    n_shards=n_shards)
     else:
-        index = tree
+        index = ckpt.restore(step, pi.empty(cfg))
 
     tail = [r for r in read_wal(os.path.join(directory, "wal"))
             if r.seq > step]

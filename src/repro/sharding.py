@@ -25,31 +25,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 Rules = Tuple[Tuple[str, object], ...]
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """Version-portable ``jax.shard_map``.
-
-    jax ≥ 0.5 exposes it as ``jax.shard_map`` with a ``check_vma`` kwarg;
-    0.4.x keeps it in ``jax.experimental.shard_map`` where the same switch
-    is called ``check_rep``.  All repro call sites go through here.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
-def axis_size(axis_name):
-    """Size of a mapped mesh axis, portable across jax versions.
-
-    ``jax.lax.axis_size`` is recent; on older jax a psum of 1 over the
-    axis gives the same value (constant-folded at trace time).
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
 # default rule set for the production meshes (see launch/mesh.py)
 DEFAULT_RULES: Rules = (
     ("batch", ("pod", "data")),

@@ -18,9 +18,11 @@ cfg = PIConfig(capacity=1024, pending_capacity=256, fanout=4)
 keys = rng.choice(100_000, size=1000, replace=False).astype(np.int32)
 vals = np.arange(1000, dtype=np.int32)
 S = 8
-state = build_sharded(cfg, S, keys, vals)
-ref = RefIndex.build(keys, vals)
 mesh = jax.make_mesh((S,), ("data",))
+state = build_sharded(cfg, S, keys, vals, mesh=mesh)
+for s, shard in enumerate(state.shards.keys.addressable_shards):
+    assert shard.index[0] == slice(s, s + 1) and shard.device == mesh.devices[s]
+ref = RefIndex.build(keys, vals)
 B = 512
 for trial in range(3):
     ops = rng.integers(0, 3, size=B).astype(np.int32)
@@ -37,7 +39,7 @@ for trial in range(3):
 k2, v2 = collect_pairs(state)
 refk = np.array(sorted(ref.data)); refv = np.array([ref.data[k] for k in refk])
 assert np.array_equal(k2, refk) and np.array_equal(v2, refv)
-state = rebuild_sharded(state)
+state = rebuild_sharded(state, mesh)
 k3, v3 = collect_pairs(state)
 assert np.array_equal(k3, refk) and np.array_equal(v3, refv)
 print("OK")
@@ -50,8 +52,8 @@ from repro.core import *
 rng = np.random.default_rng(1)
 cfg = PIConfig(capacity=1024, pending_capacity=128, fanout=4)
 keys = rng.choice(100_000, size=1000, replace=False).astype(np.int32)
-state = build_sharded(cfg, 8, keys, np.arange(1000, dtype=np.int32))
 mesh = jax.make_mesh((8,), ("data",))
+state = build_sharded(cfg, 8, keys, np.arange(1000, dtype=np.int32), mesh=mesh)
 zeros = jnp.zeros(4096, jnp.int32)
 zipf = (np.random.default_rng(2).zipf(1.5, size=4096) % 100_000).astype(np.int32)
 state, _, load, _ = execute_sharded(state, mesh, zeros, jnp.asarray(zipf), zeros)
@@ -59,7 +61,7 @@ i0 = load_imbalance(np.asarray(load))
 f2 = rebalance_from_load(np.asarray(state.fences), np.asarray(load),
                          smoothing=1.0, key_lo=0, key_hi=100_000)
 kk, vv = collect_pairs(state)
-state2 = build_sharded(cfg, 8, kk, vv, fences=f2)
+state2 = build_sharded(cfg, 8, kk, vv, fences=f2, mesh=mesh)
 state2, _, load2, _ = execute_sharded(state2, mesh, zeros, jnp.asarray(zipf), zeros)
 assert load_imbalance(np.asarray(load2)) < i0
 print("OK")

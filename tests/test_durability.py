@@ -55,8 +55,8 @@ def seeded(kind):
     keys0 = np.unique(rng.integers(1, KEY_SPACE, 25).astype(np.int32))
     vals0 = rng.integers(0, 1000, keys0.size).astype(np.int32)
     if kind == "sharded":
-        state = build_sharded(CFG, 1, keys0, vals0)
         mesh = jax.make_mesh((1,), ("data",))
+        state = build_sharded(CFG, 1, keys0, vals0, mesh=mesh)
         return state, mesh, (keys0, vals0)
     idx = build(CFG, jnp.asarray(keys0), jnp.asarray(vals0))
     return idx, None, (keys0, vals0)

@@ -27,7 +27,7 @@ def test_pi_search_sweep(rng, C, fanout, dt):
     storage = make_storage(rng, C, dt)
     q = rng.uniform(-10, C * 10 + 10, size=512).astype(dt)
     got = np.asarray(pi_search_op(jnp.asarray(storage), jnp.asarray(q),
-                                  fanout=fanout, tile_q=256))
+                                  fanout=fanout, tile_q=256, interpret=True))
     want = np.asarray(pi_search_ref(jnp.asarray(storage), jnp.asarray(q)))
     np.testing.assert_array_equal(got, want)
 
@@ -37,7 +37,7 @@ def test_pi_search_tile_sizes(rng, tile_q):
     storage = make_storage(rng, 2048, np.int32)
     q = rng.integers(0, 20_000, size=1024).astype(np.int32)
     got = np.asarray(pi_search_op(jnp.asarray(storage), jnp.asarray(q),
-                                  fanout=8, tile_q=tile_q))
+                                  fanout=8, tile_q=tile_q, interpret=True))
     want = np.asarray(pi_search_ref(jnp.asarray(storage), jnp.asarray(q)))
     np.testing.assert_array_equal(got, want)
 
@@ -48,14 +48,16 @@ def test_pi_search_exact_hits(rng):
     n = int(np.sum(storage != np.iinfo(np.int32).max))
     take = rng.choice(n, 256, replace=False)
     got = np.asarray(pi_search_op(jnp.asarray(storage),
-                                  jnp.asarray(storage[take]), fanout=8))
+                                  jnp.asarray(storage[take]), fanout=8,
+                                  interpret=True))
     np.testing.assert_array_equal(got, take)
 
 
 def test_pi_search_below_min(rng):
     storage = make_storage(rng, 256, np.int32)
     q = jnp.asarray(np.full(256, storage[0] - 1, np.int32))
-    got = np.asarray(pi_search_op(jnp.asarray(storage), q, fanout=4))
+    got = np.asarray(pi_search_op(jnp.asarray(storage), q, fanout=4,
+                                  interpret=True))
     assert np.all(got == -1)
 
 
@@ -64,7 +66,8 @@ def test_pi_search_below_min(rng):
 def test_bitonic_sweep(rng, B, dt):
     k = rng.integers(0, max(4, B // 4), size=B).astype(dt)  # many ties
     v = np.arange(B, dtype=np.int32)
-    gk, gv = map(np.asarray, bitonic_sort_op(jnp.asarray(k), jnp.asarray(v)))
+    gk, gv = map(np.asarray, bitonic_sort_op(jnp.asarray(k), jnp.asarray(v),
+                                            interpret=True))
     wk, wv = map(np.asarray, bitonic_sort_ref(jnp.asarray(k), jnp.asarray(v)))
     np.testing.assert_array_equal(gk, wk)
     np.testing.assert_array_equal(gv, wv)
@@ -73,9 +76,9 @@ def test_bitonic_sweep(rng, B, dt):
 def test_bitonic_already_sorted_and_reversed():
     k = jnp.arange(128, dtype=jnp.int32)
     v = jnp.arange(128, dtype=jnp.int32)
-    gk, gv = bitonic_sort_op(k, v)
+    gk, gv = bitonic_sort_op(k, v, interpret=True)
     np.testing.assert_array_equal(np.asarray(gk), np.arange(128))
-    gk, gv = bitonic_sort_op(k[::-1], v)
+    gk, gv = bitonic_sort_op(k[::-1], v, interpret=True)
     np.testing.assert_array_equal(np.asarray(gk), np.arange(128))
     np.testing.assert_array_equal(np.asarray(gv), np.arange(128)[::-1])
 
@@ -86,7 +89,8 @@ def test_sort_queries_kernel_is_stable(rng):
     keys = rng.integers(0, 9, B).astype(np.int32)
     vals = rng.integers(0, 50, B).astype(np.int32)
     perm, so, sk, sv = sort_queries_kernel(
-        jnp.asarray(ops), jnp.asarray(keys), jnp.asarray(vals))
+        jnp.asarray(ops), jnp.asarray(keys), jnp.asarray(vals),
+        interpret=True)
     sk, perm = np.asarray(sk), np.asarray(perm)
     assert np.array_equal(sk, np.sort(keys))
     for key in np.unique(keys):

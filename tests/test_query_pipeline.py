@@ -140,7 +140,7 @@ def test_sharded_dispatch_matches_oracle():
     keys0 = rng.choice(40, 20, replace=False).astype(np.int32)
     vals0 = rng.integers(0, 1000, 20).astype(np.int32)
     mesh = jax.make_mesh((1,), ("data",))
-    state = build_sharded(cfg, 1, keys0, vals0)
+    state = build_sharded(cfg, 1, keys0, vals0, mesh=mesh)
     ref = RefIndex.build(keys0, vals0)
     t, ops, keys, vals = make_stream(n=300, seed=5)
     col = Collector(WindowConfig(batch=32, deadline=5.0))
@@ -156,8 +156,8 @@ def test_sharded_dispatch_surfaces_routing_drops():
     queries silently — while harmless padding drops must NOT raise."""
     cfg = PIConfig(capacity=256, pending_capacity=128, fanout=4)
     keys0 = np.arange(0, 64, 2, dtype=np.int32)
-    state = build_sharded(cfg, 1, keys0, keys0)
     mesh = jax.make_mesh((1,), ("data",))
+    state = build_sharded(cfg, 1, keys0, keys0, mesh=mesh)
     # capacity_factor 0.25: a full 32-slot window offers 32 queries to the
     # single shard but only ceil(32*0.25)=8 survive routing
     disp = Dispatcher(state, mesh=mesh, depth=0, capacity_factor=0.25,
@@ -170,7 +170,7 @@ def test_sharded_dispatch_surfaces_routing_drops():
 
     # mostly-padding short batch under the same tight capacity: the pads
     # overflow the bucket, the real queries survive → no error
-    state2 = build_sharded(cfg, 1, keys0, keys0)
+    state2 = build_sharded(cfg, 1, keys0, keys0, mesh=mesh)
     disp2 = Dispatcher(state2, mesh=mesh, depth=0, capacity_factor=0.25,
                        clock=lambda: 0.0)
     col2 = Collector(WindowConfig(batch=32, coalesce=False))
@@ -183,7 +183,8 @@ def test_sharded_dispatch_surfaces_routing_drops():
 def test_sharded_dispatch_requires_mesh():
     cfg = PIConfig(capacity=64, pending_capacity=32, fanout=4)
     state = build_sharded(cfg, 1, np.arange(4, dtype=np.int32),
-                          np.arange(4, dtype=np.int32))
+                          np.arange(4, dtype=np.int32),
+                          mesh=jax.make_mesh((1,), ("data",)))
     with pytest.raises(ValueError, match="mesh"):
         Dispatcher(state)
 

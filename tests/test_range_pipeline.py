@@ -10,6 +10,7 @@ the whole serving run must compile the range executor exactly once.
 import struct
 import zlib
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -327,7 +328,10 @@ def test_sharded_fanout_parity_and_oracle(rng):
     vals = rng.integers(0, 1 << 20, keys.shape[0]).astype(np.int32)
     cfg = PIConfig(capacity=2048, pending_capacity=64, fanout=4,
                    seg_width=64, backend="xla")
-    state = build_sharded(cfg, 4, keys, vals)
+    # four shards on one device: each device reduces over the shards it
+    # holds before the cross-device psum
+    mesh = jax.make_mesh((1,), ("data",))
+    state = build_sharded(cfg, 4, keys, vals, mesh=mesh)
     single = build(PIConfig(capacity=8192, pending_capacity=64, fanout=4,
                             seg_width=64, backend="xla"),
                    jnp.asarray(keys), jnp.asarray(vals))
@@ -342,10 +346,10 @@ def test_sharded_fanout_parity_and_oracle(rng):
         los[i] = lo
         his[i] = lo + int(rng.integers(0, 50_000))
     base = range_trace_count()
-    cnt_s, sum_s = execute_ranges_sharded(state, jnp.asarray(ops),
+    cnt_s, sum_s = execute_ranges_sharded(state, mesh, jnp.asarray(ops),
                                           jnp.asarray(los),
                                           jnp.asarray(his), 8192)
-    execute_ranges_sharded(state, jnp.asarray(ops), jnp.asarray(los),
+    execute_ranges_sharded(state, mesh, jnp.asarray(ops), jnp.asarray(los),
                            jnp.asarray(his), 8192)
     trace_guard("pipeline.ranges").expect(base, 1, "repeated sharded call")
     cnt_1, sum_1 = execute_ranges(single, jnp.asarray(ops),

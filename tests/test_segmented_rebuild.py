@@ -220,7 +220,8 @@ def test_needs_rebuild_integer_precision():
 def test_not_due_shard_keeps_state_bit_for_bit(rng):
     cfg = PIConfig(capacity=256, pending_capacity=64, fanout=4)
     keys = rng.choice(10_000, 200, replace=False).astype(np.int32)
-    state = build_sharded(cfg, 2, keys, np.arange(200, dtype=np.int32))
+    state = build_sharded(cfg, 2, keys, np.arange(200, dtype=np.int32),
+                          mesh=jax.make_mesh((1,), ("data",)))
     # give BOTH shards pending churn, but only shard 0 enough to be due
     s0 = jax.tree.map(lambda x: x[0], state.shards)
     s1 = jax.tree.map(lambda x: x[1], state.shards)
@@ -234,8 +235,8 @@ def test_not_due_shard_keeps_state_bit_for_bit(rng):
                                                        np.int32)))
     assert bool(needs_rebuild(s0)) and not bool(needs_rebuild(s1))
     stacked = jax.tree.map(lambda a, b: jnp.stack([a, b]), s0, s1)
-    shards, ovf, due = maybe_rebuild_shards(stacked)
-    assert bool(due) and not bool(ovf)
+    shards, ovf, due, _ = maybe_rebuild_shards(stacked)
+    assert due.tolist() == [True, False] and not ovf.any()
     out0 = jax.tree.map(lambda x: x[0], shards)
     out1 = jax.tree.map(lambda x: x[1], shards)
     assert int(out0.pn) == 0, "due shard must have rebuilt"

@@ -80,9 +80,8 @@ from repro import optim
 mesh = jax.make_mesh((8,), ("pod",))
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding import shard_map
 
-@partial(shard_map, mesh=mesh, in_specs=(P("pod"), P("pod")),
+@partial(jax.shard_map, mesh=mesh, in_specs=(P("pod"), P("pod")),
          out_specs=(P("pod"), P("pod")), check_vma=False)
 def step(x, err):
     y, e = optim.compressed_psum(x[0], "pod", err[0])
