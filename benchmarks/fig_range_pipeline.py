@@ -119,9 +119,7 @@ def main(n_keys=1 << 15, batch=256, n_arrivals=4096):
         windowed_stats[name] = {
             "range_admitted": piped["range_admitted"],
             "range_slots": piped["range_slots"],
-            "range_coalesce_hits": piped["range_coalesce_hits"],
-            "range_span_p50": piped["range_span_p50"],
-            "range_span_p99": piped["range_span_p99"]}
+            "range_coalesce_hits": piped["range_coalesce_hits"]}
         print(f"[range] {name}: windowed {piped['qps']:,.0f} ranges/s vs "
               f"naive {naive['qps']:,.0f} ({speedups[name]:.1f}x, "
               f"{piped['range_coalesce_hits']} coalesce hits)")

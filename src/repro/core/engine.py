@@ -42,6 +42,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels.pi_range import pi_range
@@ -128,11 +129,12 @@ class SearchEngine:
         # occupied-rank tables: rank[slot] = #occupied slots at-or-before
         # slot (minus one); dense2slot[r] = slot of the r-th occupied key,
         # C past the end.  Tombstoned slots keep their key => occupied.
-        occ = index.keys != sent
-        rank = jnp.cumsum(occ.astype(jnp.int32)) - 1
-        tgt = jnp.where(occ, rank, C)
-        dense2slot = jnp.full((C,), C, jnp.int32).at[tgt].set(
-            jnp.arange(C, dtype=jnp.int32), mode="drop")
+        with jax.named_scope("pi.ranges.tables"):
+            occ = index.keys != sent
+            rank = jnp.cumsum(occ.astype(jnp.int32)) - 1
+            tgt = jnp.where(occ, rank, C)
+            dense2slot = jnp.full((C,), C, jnp.int32).at[tgt].set(
+                jnp.arange(C, dtype=jnp.int32), mode="drop")
         pidx = jnp.arange(index.pkeys.shape[0])
         plive = (pidx < index.pn) & ~index.ptomb
         if self.uses_pallas:
@@ -143,21 +145,26 @@ class SearchEngine:
                 fanout=index.config.fanout, max_span=max_span,
                 tile_q=self.tile_q, interpret=self.interpret,
                 levels=index.levels)
-        pos = self.floor(index, lo)
-        r0 = jnp.where(pos >= 0, jnp.take(rank, jnp.clip(pos, 0, C - 1)), 0)
-        r = r0[:, None] + jnp.arange(max_span, dtype=jnp.int32)[None, :]
-        slot = jnp.take(dense2slot, r, mode="fill", fill_value=C)
-        ks = jnp.take(index.keys, slot, mode="fill", fill_value=sent)
-        ts = jnp.take(index.tomb, slot, mode="fill", fill_value=True)
-        vs = jnp.take(index.vals, slot, mode="fill", fill_value=0)
-        inr = (ks >= lo[:, None]) & (ks <= hi[:, None]) & ~ts & (ks != sent)
-        cnt = jnp.sum(inr, axis=1).astype(jnp.int32)
-        sm = jnp.sum(jnp.where(inr, vs, 0), axis=1)
+        with jax.named_scope("pi.ranges.walk"):
+            pos = self.floor(index, lo)
+            r0 = jnp.where(pos >= 0,
+                           jnp.take(rank, jnp.clip(pos, 0, C - 1)), 0)
+            r = r0[:, None] + jnp.arange(max_span, dtype=jnp.int32)[None, :]
+            slot = jnp.take(dense2slot, r, mode="fill", fill_value=C)
+            ks = jnp.take(index.keys, slot, mode="fill", fill_value=sent)
+            ts = jnp.take(index.tomb, slot, mode="fill", fill_value=True)
+            vs = jnp.take(index.vals, slot, mode="fill", fill_value=0)
+            inr = (ks >= lo[:, None]) & (ks <= hi[:, None]) & ~ts \
+                & (ks != sent)
+            cnt = jnp.sum(inr, axis=1).astype(jnp.int32)
+            sm = jnp.sum(jnp.where(inr, vs, 0), axis=1)
         # pending buffer: broadcast compare (PC is small between rebuilds)
-        pin = (index.pkeys[None, :] >= lo[:, None]) & \
-            (index.pkeys[None, :] <= hi[:, None]) & plive[None, :]
-        cnt = cnt + jnp.sum(pin, axis=1).astype(jnp.int32)
-        sm = sm + jnp.sum(jnp.where(pin, index.pvals[None, :], 0), axis=1)
+        with jax.named_scope("pi.ranges.pending"):
+            pin = (index.pkeys[None, :] >= lo[:, None]) & \
+                (index.pkeys[None, :] <= hi[:, None]) & plive[None, :]
+            cnt = cnt + jnp.sum(pin, axis=1).astype(jnp.int32)
+            sm = sm + jnp.sum(jnp.where(pin, index.pvals[None, :], 0),
+                              axis=1)
         return cnt, sm
 
     # -- xla backend -------------------------------------------------------

@@ -365,64 +365,71 @@ def execute_impl(index: PIIndex, ops: jnp.ndarray, qkeys: jnp.ndarray,
     kdt = index.keys.dtype
     sent = _sentinel(kdt)
 
-    perm, s_ops, s_keys, s_vals = sort_queries(ops, qkeys.astype(kdt), qvals)
-    newseg = jnp.concatenate(
-        [jnp.ones((1,), bool), s_keys[1:] != s_keys[:-1]])
-    is_write = s_ops != SEARCH
-    is_del = s_ops == DELETE
-    (inc_has, inc_val, inc_tomb), (exc_has, exc_val, exc_tomb) = (
-        seg_last_write_scan(newseg, is_write, s_vals, is_del))
+    with jax.named_scope("pi.step.order"):
+        perm, s_ops, s_keys, s_vals = sort_queries(ops, qkeys.astype(kdt),
+                                                   qvals)
+        newseg = jnp.concatenate(
+            [jnp.ones((1,), bool), s_keys[1:] != s_keys[:-1]])
+        is_write = s_ops != SEARCH
+        is_del = s_ops == DELETE
+        (inc_has, inc_val, inc_tomb), (exc_has, exc_val, exc_tomb) = (
+            seg_last_write_scan(newseg, is_write, s_vals, is_del))
 
     # --- store state per query (pre-batch view, one fused engine probe) ---
-    pos, main_match, main_live, main_val, ppos, p_match, p_live = _probe(
-        index, s_keys)
-    pos_c = jnp.maximum(pos, 0)
-    store_found = main_live | p_live
-    store_val = jnp.where(p_live, jnp.take(index.pvals, ppos), main_val)
+    with jax.named_scope("pi.step.probe"):
+        pos, main_match, main_live, main_val, ppos, p_match, p_live = \
+            _probe(index, s_keys)
+        pos_c = jnp.maximum(pos, 0)
+        store_found = main_live | p_live
+        store_val = jnp.where(p_live, jnp.take(index.pvals, ppos), main_val)
 
     # --- per-query results (visibility: exclusive scan > store) -----------
-    vis_found = jnp.where(exc_has, ~exc_tomb, store_found)
-    vis_val = jnp.where(exc_has, exc_val, store_val)
-    r_found = jnp.where(s_ops == SEARCH, vis_found,
-                        jnp.where(is_del, vis_found, False))
-    r_val = jnp.where(s_ops == SEARCH, jnp.where(vis_found, vis_val, 0),
-                      jnp.where(is_del & vis_found, 1, 0))
+    with jax.named_scope("pi.step.order"):
+        vis_found = jnp.where(exc_has, ~exc_tomb, store_found)
+        vis_val = jnp.where(exc_has, exc_val, store_val)
+        r_found = jnp.where(s_ops == SEARCH, vis_found,
+                            jnp.where(is_del, vis_found, False))
+        r_val = jnp.where(s_ops == SEARCH, jnp.where(vis_found, vis_val, 0),
+                          jnp.where(is_del & vis_found, 1, 0))
 
-    inv = jnp.argsort(perm)
-    results = (r_found[inv], r_val[inv])
+        inv = jnp.argsort(perm)
+        results = (r_found[inv], r_val[inv])
 
     # --- net effects: one writer per key segment (segment tails) ----------
-    seg_end = jnp.concatenate([newseg[1:], jnp.ones((1,), bool)])
-    apply_w = seg_end & inc_has
-    # 1) key already in main storage → in-place update (Alg. 4 lines 11-15)
-    upd_main = apply_w & main_match
-    tgt = jnp.where(upd_main, pos_c, cfg.capacity)  # OOB ⇒ dropped
-    vals2 = index.vals.at[tgt].set(
-        jnp.where(inc_tomb, main_val, inc_val), mode="drop")
-    tomb2 = index.tomb.at[tgt].set(inc_tomb, mode="drop")
-    # 2) key in pending buffer → in-place update there
-    upd_pend = apply_w & ~main_match & p_match
-    ptgt = jnp.where(upd_pend, ppos, cfg.pending_capacity)
-    pvals2 = index.pvals.at[ptgt].set(
-        jnp.where(inc_tomb, jnp.take(index.pvals, ppos), inc_val), mode="drop")
-    ptomb2 = index.ptomb.at[ptgt].set(inc_tomb, mode="drop")
+    with jax.named_scope("pi.step.apply"):
+        seg_end = jnp.concatenate([newseg[1:], jnp.ones((1,), bool)])
+        apply_w = seg_end & inc_has
+        # 1) key already in main storage → in-place update (Alg. 4 lines 11-15)
+        upd_main = apply_w & main_match
+        tgt = jnp.where(upd_main, pos_c, cfg.capacity)  # OOB ⇒ dropped
+        vals2 = index.vals.at[tgt].set(
+            jnp.where(inc_tomb, main_val, inc_val), mode="drop")
+        tomb2 = index.tomb.at[tgt].set(inc_tomb, mode="drop")
+        # 2) key in pending buffer → in-place update there
+        upd_pend = apply_w & ~main_match & p_match
+        ptgt = jnp.where(upd_pend, ppos, cfg.pending_capacity)
+        pvals2 = index.pvals.at[ptgt].set(
+            jnp.where(inc_tomb, jnp.take(index.pvals, ppos), inc_val),
+            mode="drop")
+        ptomb2 = index.ptomb.at[ptgt].set(inc_tomb, mode="drop")
     # 3) brand-new key, net insert → append to pending (sorted merge)
-    new_ins = apply_w & ~main_match & ~p_match & ~inc_tomb
-    addk = jnp.where(new_ins, s_keys, sent)
-    addv = jnp.where(new_ins, inc_val, 0)
-    mk = jnp.concatenate([index.pkeys, addk])
-    mv = jnp.concatenate([pvals2, addv])
-    mt = jnp.concatenate([ptomb2, jnp.zeros((B,), bool)])
-    # hide slots beyond pn so stale tails don't resurrect
-    pidx = jnp.arange(cfg.pending_capacity)
-    mk = mk.at[:cfg.pending_capacity].set(
-        jnp.where(pidx < index.pn, mk[:cfg.pending_capacity], sent))
-    order = jnp.argsort(mk)
-    mk, mv, mt = mk[order], mv[order], mt[order]
-    pn2 = jnp.minimum(index.pn + jnp.sum(new_ins),
-                      cfg.pending_capacity).astype(jnp.int32)
-    overflow2 = index.overflow | (
-        index.pn + jnp.sum(new_ins) > cfg.pending_capacity)
+    with jax.named_scope("pi.step.merge"):
+        new_ins = apply_w & ~main_match & ~p_match & ~inc_tomb
+        addk = jnp.where(new_ins, s_keys, sent)
+        addv = jnp.where(new_ins, inc_val, 0)
+        mk = jnp.concatenate([index.pkeys, addk])
+        mv = jnp.concatenate([pvals2, addv])
+        mt = jnp.concatenate([ptomb2, jnp.zeros((B,), bool)])
+        # hide slots beyond pn so stale tails don't resurrect
+        pidx = jnp.arange(cfg.pending_capacity)
+        mk = mk.at[:cfg.pending_capacity].set(
+            jnp.where(pidx < index.pn, mk[:cfg.pending_capacity], sent))
+        order = jnp.argsort(mk)
+        mk, mv, mt = mk[order], mv[order], mt[order]
+        pn2 = jnp.minimum(index.pn + jnp.sum(new_ins),
+                          cfg.pending_capacity).astype(jnp.int32)
+        overflow2 = index.overflow | (
+            index.pn + jnp.sum(new_ins) > cfg.pending_capacity)
 
     n_upd = index.n_updates + jnp.sum(apply_w).astype(jnp.int32)
     new_index = PIIndex(
@@ -536,6 +543,7 @@ def incremental_fits(index: PIIndex) -> jnp.ndarray:
     return (n_dirty <= D) & jnp.all(cnt + npend <= W)
 
 
+@jax.named_scope("pi.rebuild.incremental")
 def _rebuild_incremental(index: PIIndex) -> PIIndex:
     """Churn-proportional rebuild: merge pending keys into dirty segments.
 
@@ -625,6 +633,7 @@ def _rebuild_incremental(index: PIIndex) -> PIIndex:
         **_fresh_pending(cfg, kdt))
 
 
+@jax.named_scope("pi.rebuild.repack")
 def _rebuild_repack(index: PIIndex) -> PIIndex:
     """Full repack (paper §4.1/§4.3.5, made a sort+spread).
 
@@ -687,12 +696,13 @@ def rebuild_if_due(index: PIIndex):
     probe lives inside the due-branch, so a window that does not rebuild
     pays nothing for it.
     """
-    due = needs_rebuild(index)
-    index, incr = jax.lax.cond(
-        due,
-        lambda i: (rebuild(i), incremental_fits(i) & ~i.overflow),
-        lambda i: (i, jnp.array(False)),
-        index)
+    with jax.named_scope("pi.step.rebuild"):
+        due = needs_rebuild(index)
+        index, incr = jax.lax.cond(
+            due,
+            lambda i: (rebuild(i), incremental_fits(i) & ~i.overflow),
+            lambda i: (i, jnp.array(False)),
+            index)
     return index, due, incr
 
 

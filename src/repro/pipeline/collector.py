@@ -49,6 +49,7 @@ import numpy as np
 
 from repro.core.batch import DELETE, INSERT, RANGE, SEARCH
 from repro.kernels.pi_search import sentinel_for
+from repro.pipeline.metrics import span
 
 TRIGGER_SIZE = "size"
 TRIGGER_DEADLINE = "deadline"
@@ -336,6 +337,10 @@ class Collector:
         Times must be nondecreasing (arrival order); all arrays are 1-D
         of one shared length.
         """
+        with span("collect.offer"):
+            return self._offer_many(t, ops, keys, vals, qids, keys2)
+
+    def _offer_many(self, t, ops, keys, vals, qids, keys2):
         t = np.ascontiguousarray(t, np.float64)
         ops = np.ascontiguousarray(ops, np.int32)
         keys = np.ascontiguousarray(keys, np.dtype(self.cfg.key_dtype))
@@ -655,13 +660,14 @@ class Collector:
         """
         if not self._n_arr:
             return None
-        if self._n >= self._batch:
-            trigger = TRIGGER_SIZE
-        elif now is not None and self._expired(now):
-            trigger = TRIGGER_DEADLINE
-        else:
-            trigger = TRIGGER_FLUSH
-        return self._seal(trigger)
+        with span("collect.take"):
+            if self._n >= self._batch:
+                trigger = TRIGGER_SIZE
+            elif now is not None and self._expired(now):
+                trigger = TRIGGER_DEADLINE
+            else:
+                trigger = TRIGGER_FLUSH
+            return self._seal(trigger)
 
     def _flush_tail(self):
         """Close the scalar tail lists into arrival segments."""
@@ -675,24 +681,26 @@ class Collector:
 
     def _seal(self, trigger: str) -> Window:
         """Pad the slot buffers, concatenate arrival segments, hand off."""
-        n = self._n
-        ops, keys, vals = self._buf_ops, self._buf_keys, self._buf_vals
-        keys2 = self._buf_keys2
-        ops[n:] = SEARCH
-        keys[n:] = self._sent
-        keys2[n:] = 0
-        vals[n:] = 0
-        self._flush_tail()
-        qids: List[int] = []
-        for seg in self._seg_qids:
-            qids.extend(seg.tolist() if isinstance(seg, np.ndarray) else seg)
-        win = Window(ops=ops, keys=keys, vals=vals, occupancy=n,
-                     qids=qids,
-                     slots=np.concatenate(self._seg_slots),
-                     t_open=float(self._t_open),
-                     t_enq=np.concatenate(self._seg_tenq),
-                     trigger=trigger, keys2=keys2)
-        self._reset()
-        if self.on_seal is not None:
-            self.on_seal(win)
-        return win
+        with span("collect.seal"):
+            n = self._n
+            ops, keys, vals = self._buf_ops, self._buf_keys, self._buf_vals
+            keys2 = self._buf_keys2
+            ops[n:] = SEARCH
+            keys[n:] = self._sent
+            keys2[n:] = 0
+            vals[n:] = 0
+            self._flush_tail()
+            qids: List[int] = []
+            for seg in self._seg_qids:
+                qids.extend(seg.tolist() if isinstance(seg, np.ndarray)
+                            else seg)
+            win = Window(ops=ops, keys=keys, vals=vals, occupancy=n,
+                         qids=qids,
+                         slots=np.concatenate(self._seg_slots),
+                         t_open=float(self._t_open),
+                         t_enq=np.concatenate(self._seg_tenq),
+                         trigger=trigger, keys2=keys2)
+            self._reset()
+            if self.on_seal is not None:
+                self.on_seal(win)
+            return win

@@ -54,7 +54,7 @@ from repro.core import index as pi
 from repro.core.batch import RANGE, SEARCH
 from repro.kernels.pi_search import sentinel_for
 from repro.pipeline.collector import Collector, Window, WindowConfig
-from repro.pipeline.metrics import PipelineMetrics
+from repro.pipeline.metrics import PipelineMetrics, span
 from repro.pipeline.overload import (BREAKER_CLOSED, BREAKER_POISONED,
                                      BREAKER_READ_ONLY, BREAKER_RECOVERING,
                                      OverloadConfig, ReadOnlyModeError)
@@ -364,18 +364,21 @@ class Dispatcher:
         # writes sound.  Read-only, so failure-free w.r.t. the breaker.
         rcnt = rsum = None
         if self._window_has_ranges(window):
-            rcnt, rsum = self._execute_ranges(window)
-        ops, keys = self._point_view(window)
-        found, val, ovf, rebuilt, incr, dropped, pn = self._step(
-            jnp.asarray(ops), jnp.asarray(keys),
-            jnp.asarray(window.vals))
+            with span("dispatch.ranges"):
+                rcnt, rsum = self._execute_ranges(window)
+        with span("dispatch.launch"):
+            ops, keys = self._point_view(window)
+            found, val, ovf, rebuilt, incr, dropped, pn = self._step(
+                jnp.asarray(ops), jnp.asarray(keys),
+                jnp.asarray(window.vals))
         self._inflight.append(
             _InFlight(window, found, val, ovf, rebuilt, incr, dropped,
                       pn=pn, rcnt=rcnt, rsum=rsum, pre_index=pre))
         if self.durability is not None:
             # the new index state reflects every window up to and
             # including this one, so window.seq is its WAL position
-            self.durability.maybe_snapshot(self._index, window.seq)
+            with span("dispatch.snapshot"):
+                self.durability.maybe_snapshot(self._index, window.seq)
         retired = []
         while len(self._inflight) > self.depth:
             retired.append(self._retire_front())
@@ -568,31 +571,33 @@ class Dispatcher:
         return res
 
     def _retire(self, infl: _InFlight) -> WindowResult:
-        found = np.asarray(infl.found)   # blocks on the device here
-        val = np.asarray(infl.val)
-        if self.check_overflow and bool(infl.overflow):
-            raise PendingOverflowError(
-                "pending buffer overflowed while executing a window: net "
-                "inserts were dropped.  Grow PIConfig.pending_capacity "
-                "above the window batch, or rebuild more aggressively.")
-        if self.check_overflow and infl.dropped is not None \
-                and int(infl.dropped) > 0:
-            raise DispatchOverflowError(
-                f"fence routing dropped {int(infl.dropped)} queries: a "
-                f"shard's send bucket overflowed.  Raise capacity_factor "
-                f"({self.capacity_factor}) or rebalance the fences.")
-        res = WindowResult(window=infl.window, found=found, val=val,
-                           t_retired=self._clock(),
-                           rebuilt=bool(infl.rebuilt),
-                           rebuilt_incremental=(
-                               infl.incr is not None and bool(infl.incr)),
-                           pending_fill=(
-                               int(infl.pn) / self._pending_capacity
-                               if infl.pn is not None else float("nan")),
-                           rcnt=(np.asarray(infl.rcnt)
-                                 if infl.rcnt is not None else None),
-                           rsum=(np.asarray(infl.rsum)
-                                 if infl.rsum is not None else None))
-        if self.metrics is not None:
-            self.metrics.on_retire(res)
-        return res
+        with span("dispatch.wait"):
+            found = np.asarray(infl.found)   # blocks on the device here
+        with span("dispatch.result"):
+            val = np.asarray(infl.val)
+            if self.check_overflow and bool(infl.overflow):
+                raise PendingOverflowError(
+                    "pending buffer overflowed while executing a window: net "
+                    "inserts were dropped.  Grow PIConfig.pending_capacity "
+                    "above the window batch, or rebuild more aggressively.")
+            if self.check_overflow and infl.dropped is not None \
+                    and int(infl.dropped) > 0:
+                raise DispatchOverflowError(
+                    f"fence routing dropped {int(infl.dropped)} queries: a "
+                    f"shard's send bucket overflowed.  Raise capacity_factor "
+                    f"({self.capacity_factor}) or rebalance the fences.")
+            res = WindowResult(window=infl.window, found=found, val=val,
+                               t_retired=self._clock(),
+                               rebuilt=bool(infl.rebuilt),
+                               rebuilt_incremental=(
+                                   infl.incr is not None and bool(infl.incr)),
+                               pending_fill=(
+                                   int(infl.pn) / self._pending_capacity
+                                   if infl.pn is not None else float("nan")),
+                               rcnt=(np.asarray(infl.rcnt)
+                                     if infl.rcnt is not None else None),
+                               rsum=(np.asarray(infl.rsum)
+                                     if infl.rsum is not None else None))
+            if self.metrics is not None:
+                self.metrics.on_retire(res)
+            return res

@@ -1,4 +1,4 @@
-"""Pipeline observability: latency histograms, occupancy, qps.
+"""Pipeline observability: latency histograms, occupancy, qps, host spans.
 
 Per-query latency is enqueue→result: the time axis is whatever the caller
 fed the collector (wall-clock in the benchmark and server, virtual time in
@@ -7,15 +7,71 @@ axis.  Latencies land in a log-bucketed histogram — memory-bounded no
 matter how long the pipeline runs, with percentile error bounded by the
 bucket ratio (~7% with 48 buckets per 1e6 span), which is far below the
 run-to-run noise of any wall-clock measurement on a shared host.
+
+Host spans (``span``) name the serving path's phases — collect, WAL,
+dispatch — once per window or call, never per operation.  Each is a
+``jax.profiler.TraceAnnotation`` named ``pi.<name>``, so under a running
+profiler it lands on the host plane on the device trace's clock; with no
+profiler running the annotation records nothing.  Either way the span's
+``perf_counter`` seconds go into a process-wide table of (count, total,
+longest) per name, which ``span_table`` snapshots.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional
+import time
+from typing import Dict, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.batch import RANGE
+
+
+SPAN_PREFIX = "pi."
+
+# name -> [count, total seconds, longest seconds]
+_SPANS: Dict[str, list] = {}
+
+
+class span:
+    """Context manager timing one phase of the serving path as
+    ``pi.<name>``: a profiler annotation and a row of the span table."""
+
+    __slots__ = ("name", "_ann", "_t")
+
+    def __init__(self, name: str):
+        self.name = SPAN_PREFIX + name
+        self._ann = TraceAnnotation(self.name)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        d = time.perf_counter() - self._t
+        self._ann.__exit__(*exc)
+        row = _SPANS.get(self.name)
+        if row is None:
+            _SPANS[self.name] = [1, d, d]
+        else:
+            row[0] += 1
+            row[1] += d
+            if d > row[2]:
+                row[2] = d
+        return False
+
+
+def span_table() -> Dict[str, Tuple[int, float, float]]:
+    """Snapshot of the span table: ``pi.<name>`` -> (count, total
+    seconds, longest seconds) since the process started or the last
+    ``clear_span_table``."""
+    return {k: tuple(v) for k, v in _SPANS.items()}
+
+
+def clear_span_table():
+    _SPANS.clear()
 
 
 class LatencyHistogram:
@@ -89,9 +145,6 @@ class PipelineMetrics:
     range_admitted: int = 0     # RANGE arrivals admitted (pre-coalescing)
     range_slots: int = 0        # distinct RANGE result slots executed
     range_coalesce_hits: int = 0  # RANGE arrivals that shared a queued slot
-    range_span_hist: LatencyHistogram = dataclasses.field(
-        default_factory=lambda: LatencyHistogram(1.0, 1e9, 96))
-    #   inclusive span (hi - lo + 1, key units) per distinct RANGE slot
     t_start: Optional[float] = None
     t_stop: Optional[float] = None
 
@@ -131,10 +184,6 @@ class PipelineMetrics:
                 self.range_admitted += nr_arr
                 self.range_slots += nr_slots
                 self.range_coalesce_hits += nr_arr - nr_slots
-                lo = np.asarray(w.keys)[:w.occupancy][is_r]
-                hi = np.asarray(keys2)[:w.occupancy][is_r]
-                self.range_span_hist.record(
-                    hi.astype(np.int64) - lo.astype(np.int64) + 1)
         self.hist.record(res.latencies())
 
     # -- readout -----------------------------------------------------------
@@ -174,8 +223,6 @@ class PipelineMetrics:
             "range_admitted": self.range_admitted,
             "range_slots": self.range_slots,
             "range_coalesce_hits": self.range_coalesce_hits,
-            "range_span_p50": self.range_span_hist.percentile(50),
-            "range_span_p99": self.range_span_hist.percentile(99),
             "qps": (self.n_arrivals / wall) if wall else None,
             "p50_ms": self.hist.percentile(50) * 1e3,
             "p95_ms": self.hist.percentile(95) * 1e3,

@@ -37,6 +37,7 @@ from repro.checkpoint import CheckpointManager
 from repro.core import distributed as dist
 from repro.core import index as pi
 from repro.pipeline.dispatcher import Dispatcher
+from repro.pipeline.metrics import span
 from repro.pipeline.wal import (WalRecord, WalWriter, read_wal,
                                 record_window)
 
@@ -162,8 +163,9 @@ class Durability:
         if seq is None:
             seq = self.wal.last_seq
         prev_pending = self._truncate_pending
-        self.ckpt.save(seq, _snapshot_tree(index), blocking=blocking,
-                       meta={"wal_seq": seq, "kind": self.kind})
+        with span("ckpt.save"):
+            self.ckpt.save(seq, _snapshot_tree(index), blocking=blocking,
+                           meta={"wal_seq": seq, "kind": self.kind})
         self._last_snap = seq
         if blocking:
             self._truncate()

@@ -66,6 +66,7 @@ from repro.core.batch import SEARCH
 from repro.faults import faultpoint
 from repro.kernels.pi_search import sentinel_for
 from repro.pipeline.collector import Window
+from repro.pipeline.metrics import span
 
 MAGIC_V1 = b"PIW1"
 MAGIC = b"PIW2"
@@ -359,37 +360,40 @@ class WalWriter:
         seq is a wiring bug — two writers, or a collector resumed without
         the log — and is refused before any bytes are written.
         """
-        seq = self._next_seq
-        if window.seq is not None and window.seq != seq:
-            raise ValueError(
-                f"window carries seq {window.seq} but the log is at "
-                f"{seq}: windows must reach the WAL in seal order")
-        blob = encode_record(seq, window)
-        half = len(blob) // 2
-        self._f.write(blob[:half])
-        faultpoint("wal.mid_append")               # torn record on crash
-        self._f.write(blob[half:])
-        faultpoint("wal.after_append")             # written, not yet synced
-        window.seq = seq
-        self._next_seq = seq + 1
-        self._bytes += len(blob)
-        self.n_appends += 1
-        self._unsynced += 1
-        if self.fsync == "per_window":
-            self.sync()
-        elif self.fsync == "interval" and (
-                (self.group_commit is not None and
-                 self._unsynced >= self.group_commit) or
-                time.monotonic() - self._t_last_fsync >= self.fsync_interval):
-            self.sync()
-        if self._bytes >= self.segment_bytes:
-            self._rotate()
+        with span("wal.append"):
+            seq = self._next_seq
+            if window.seq is not None and window.seq != seq:
+                raise ValueError(
+                    f"window carries seq {window.seq} but the log is at "
+                    f"{seq}: windows must reach the WAL in seal order")
+            blob = encode_record(seq, window)
+            half = len(blob) // 2
+            self._f.write(blob[:half])
+            faultpoint("wal.mid_append")           # torn record on crash
+            self._f.write(blob[half:])
+            faultpoint("wal.after_append")         # written, not yet synced
+            window.seq = seq
+            self._next_seq = seq + 1
+            self._bytes += len(blob)
+            self.n_appends += 1
+            self._unsynced += 1
+            if self.fsync == "per_window":
+                self.sync()
+            elif self.fsync == "interval" and (
+                    (self.group_commit is not None and
+                     self._unsynced >= self.group_commit) or
+                    time.monotonic() - self._t_last_fsync
+                    >= self.fsync_interval):
+                self.sync()
+            if self._bytes >= self.segment_bytes:
+                self._rotate()
         return seq
 
     def sync(self):
         """fsync the current segment; advances the acknowledged frontier."""
         faultpoint("wal.pre_sync")    # written in full, ack not yet durable
-        os.fsync(self._f.fileno())
+        with span("wal.fsync"):
+            os.fsync(self._f.fileno())
         self.durable_seq = self.last_seq
         self.n_fsyncs += 1
         self._unsynced = 0
