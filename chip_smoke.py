@@ -246,8 +246,8 @@ def serve(disp, stream, batch: int, log: CompileLog, out, *,
     """Warm up, then serve the whole stream; returns retired windows."""
     from repro.pipeline import Dispatcher, WindowConfig
 
-    # the steps donate nothing, so a throwaway dispatcher can compile them
-    # on the live index without changing it
+    # a dispatcher donates only its own copy of the index it is given, so
+    # a throwaway one can compile the steps without changing the live index
     warm = Dispatcher(disp.index, mesh=mesh, depth=0)
     with log.listening():
         t0 = time.perf_counter()

@@ -17,6 +17,21 @@ program; a ``ShardedPIIndex`` goes through
 window's per-shard slices with one ``all_to_all`` each way — the
 dispatcher is the same either way.
 
+Donation: the dispatcher owns the local index state it executes against.
+It takes a private device copy of the caller's ``PIIndex`` at
+construction, so the caller's arrays are never invalidated, and from then
+on every window's state is one the dispatcher produced itself.  While the
+circuit breaker is disarmed the step donates that state
+(``_step_single_donated``): XLA updates the capacity-sized leaves in place
+instead of copying them into a fresh output every window.  Donating a
+pending output does not block the launch, so the host still runs ahead.
+A window's RANGE launch reads the pre-window state before the step is
+enqueued; a donating execution waits for the reads already queued on its
+inputs, so the ranges still see the window-boundary state.  With the
+breaker armed the step retains its input (``_step_single``), because the
+rollback needs the pre-window state; breaker replay and the sharded path
+never donate.  ``Dispatcher.index`` is valid until the next ``submit``.
+
 Failure contract: the core's pending-buffer ``overflow`` flag means a net
 insert was silently dropped — data loss.  The collector's backpressure
 makes it unreachable under normal policy (a window can net-insert at most
@@ -31,13 +46,14 @@ same snapshot mechanism, so none of these checks force an early sync.
 
 With an ``OverloadConfig`` installed, a pending overflow is no longer
 fatal: a **circuit breaker** (DESIGN.md §8) rolls the index back to the
-state before the failing window (kept for free — nothing is donated, so
-the pre-execute buffers are intact), forces a full repack to reclaim the
-pending space, replays the quarantined windows through the same execute
-path, and resumes.  Repeated trips within a rolling interval degrade to a
-read-only mode (write windows rejected with ``ReadOnlyModeError``, reads
-served); only an unrecoverable replay — overflow on an *empty* pending
-buffer, a geometry error — latches the legacy poisoned state.
+state before the failing window (kept because an armed breaker runs the
+retaining step, so the pre-execute buffers are intact), forces a full
+repack to reclaim the pending space, replays the quarantined windows
+through the same execute path, and resumes.  Repeated trips within a
+rolling interval degrade to a read-only mode (write windows rejected with
+``ReadOnlyModeError``, reads served); only an unrecoverable replay —
+overflow on an *empty* pending buffer, a geometry error — latches the
+legacy poisoned state.
 """
 from __future__ import annotations
 
@@ -88,11 +104,10 @@ def _step_single(index, ops, keys, vals):
     """Execute + overflow snapshot + rebuild-if-due, ONE dispatch.
 
     Fused so a window costs a single device program: eager ``lax.cond``
-    per window was ~15x the execute itself.  Deliberately NOT donating the
-    index (unlike ``core.execute``): buffer donation forces the CPU client
-    into synchronous dispatch, which serializes host formation with device
-    execution — the exact overlap double-buffering exists to create.  The
-    price is one transient extra copy of the index state in memory.
+    per window was ~15x the execute itself.  This program retains its
+    input index: the breaker-armed path keeps the pre-window state for
+    rollback.  ``_step_single_donated`` is the same body compiled to
+    update the dispatcher's own state in place (module docstring).
 
     ``incr`` reports which tier a due rebuild took (``pi.rebuild_if_due``).
     """
@@ -101,6 +116,24 @@ def _step_single(index, ops, keys, vals):
     pn = new_index.pn  # fill high-water: post-rebuild pn is ~always zero
     new_index, due, incr = pi.rebuild_if_due(new_index)
     return new_index, found, val, ovf, due, incr, pn
+
+
+# Same body and program name as ``_step_single``, but the index is donated:
+# without it XLA copies every capacity-sized leaf the step writes into a
+# fresh output each window.  Only the dispatcher's own state is donated.
+_step_single_donated = jax.jit(  # pilint: disable=PI003 — owned state only
+    _step_single.__wrapped__, donate_argnums=0)
+# the program the twin above was compiled from: a step substituted for
+# ``_step_single`` (a planted fault, say) has no donating twin, so the
+# dispatcher runs it as given, retaining its input
+_DONATED_TWIN_OF = _step_single
+
+
+@jax.jit
+def _own(index):
+    """A private device copy of a caller's index, so that donating it
+    never invalidates an array the caller still holds."""
+    return jax.tree.map(jnp.copy, index)
 
 
 @jax.jit
@@ -181,9 +214,9 @@ class _InFlight:
     pn: Optional[jnp.ndarray] = None  # pending fill high-water (pre-rebuild)
     rcnt: Optional[jnp.ndarray] = None  # RANGE counts (pre-window state)
     rsum: Optional[jnp.ndarray] = None  # RANGE value sums
-    # index state BEFORE this window's execute — free to keep because
-    # _step_single doesn't donate; the breaker rolls back to it on a trip.
-    # Only retained when the breaker is armed (it pins device memory).
+    # index state BEFORE this window's execute, kept only when the breaker
+    # is armed (it pins device memory): the window then ran the retaining
+    # _step_single, and the breaker rolls back to it on a trip.
     pre_index: Optional[object] = None
 
 
@@ -198,9 +231,11 @@ class Dispatcher:
                  durability=None,
                  overload: Optional[OverloadConfig] = None,
                  clock=time.perf_counter):
-        if isinstance(index, dist.ShardedPIIndex) and mesh is None:
+        sharded = isinstance(index, dist.ShardedPIIndex)
+        if sharded and mesh is None:
             raise ValueError("a ShardedPIIndex needs its mesh for routing")
-        self._index = index
+        # the local state is donated window by window: own a copy of it
+        self._index = index if sharded else _own(index)
         self._mesh = mesh
         self.depth = max(0, int(depth))
         self.check_overflow = check_overflow
@@ -235,7 +270,9 @@ class Dispatcher:
 
     @property
     def index(self):
-        """Current index state (futures included — reading it may sync)."""
+        """Current index state (futures included — reading it may sync).
+
+        Valid until the next ``submit``, which may donate it."""
         return self._index
 
     @property
@@ -284,9 +321,10 @@ class Dispatcher:
 
     # -- execution ---------------------------------------------------------
 
-    def _step(self, ops, keys, vals):
+    def _step(self, ops, keys, vals, retain: bool):
         """One execute + rebuild-if-due → (found, val, ovf, rebuilt, incr,
-        drop, pn)."""
+        drop, pn).  ``retain`` keeps the input state intact (breaker
+        armed); otherwise the local step donates it."""
         if isinstance(self._index, dist.ShardedPIIndex):
             state, (found, val), _, dropped = dist.execute_sharded(
                 self._index, self._mesh, ops, keys, vals,
@@ -296,8 +334,13 @@ class Dispatcher:
             self._index, f = dist.maybe_rebuild_sharded(state, self._mesh)
             return (found, val, f["overflow"], f["rebuilt"],
                     f["incremental"], jnp.sum(dropped), f["pn"])
-        self._index, found, val, ovf, rebuilt, incr, pn = _step_single(
-            self._index, ops, keys, vals)
+        if retain or _step_single is not _DONATED_TWIN_OF:
+            step, name = _step_single, "dispatch.retained"
+        else:
+            step, name = _step_single_donated, "dispatch.donated"
+        with span(name):
+            self._index, found, val, ovf, rebuilt, incr, pn = step(
+                self._index, ops, keys, vals)
         return found, val, ovf, rebuilt, incr, None, pn
 
     def _window_has_writes(self, window: Window) -> bool:
@@ -357,11 +400,15 @@ class Dispatcher:
                 f"breaker trips: window with writes rejected (searches "
                 f"still serve).  Retry after the breaker closes, or "
                 f"reset_breaker() to override.")
-        pre = self._index if self._breaker_armed() else None
+        armed = self._breaker_armed()
+        pre = self._index if armed else None
         # ranges first, against the pre-execute state: every RANGE in the
         # window observes the index as of the window boundary (DESIGN.md
         # §9), which is what makes exact-pair coalescing across window
         # writes sound.  Read-only, so failure-free w.r.t. the breaker.
+        # The step launched next may donate that state: a donating
+        # execution waits for the reads already queued on its inputs, so
+        # this launch still reads the pre-window buffers.
         rcnt = rsum = None
         if self._window_has_ranges(window):
             with span("dispatch.ranges"):
@@ -370,7 +417,7 @@ class Dispatcher:
             ops, keys = self._point_view(window)
             found, val, ovf, rebuilt, incr, dropped, pn = self._step(
                 jnp.asarray(ops), jnp.asarray(keys),
-                jnp.asarray(window.vals))
+                jnp.asarray(window.vals), retain=armed)
         self._inflight.append(
             _InFlight(window, found, val, ovf, rebuilt, incr, dropped,
                       pn=pn, rcnt=rcnt, rsum=rsum, pre_index=pre))
@@ -486,14 +533,14 @@ class Dispatcher:
 
         The overflowing execute dropped net inserts, so the post-execute
         state is corrupt — but the state *before* the failing window is
-        still on device (``pre_index``; nothing is donated), and every
-        window that executed after it is still in flight with its inputs
-        intact.  Roll back, force a full repack (empties the pending
-        buffer — the resource that overflowed), and replay every
-        quarantined window through the always-rebuild recovery step.  A
-        replay that overflows *from an empty pending buffer* is a
-        geometry error (one window nets more inserts than the whole
-        buffer) and latches poisoned for real.
+        still on device (``pre_index``; an armed breaker runs the
+        retaining step), and every window that executed after it is still
+        in flight with its inputs intact.  Roll back, force a full repack
+        (empties the pending buffer — the resource that overflowed), and
+        replay every quarantined window through the always-rebuild
+        recovery step.  A replay that overflows *from an empty pending
+        buffer* is a geometry error (one window nets more inserts than the
+        whole buffer) and latches poisoned for real.
 
         Escalation: recoveries inside one rolling ``recovery_interval``
         beyond ``max_recoveries`` degrade the breaker to read-only; a trip

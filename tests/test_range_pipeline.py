@@ -200,6 +200,108 @@ def test_pipeline_ranges_match_oracle_replay_across_rebuilds():
     assert len(points) == int(np.count_nonzero(ops != RANGE))
 
 
+# ---------------------------------------------------------------------------
+# donation: the dispatcher owns, and donates, only its own index state
+# ---------------------------------------------------------------------------
+
+DONATION_CFG = PIConfig(capacity=2048, pending_capacity=64, fanout=4,
+                        seg_width=64, backend="xla")
+
+
+def donation_seed(seed=23):
+    rng = np.random.default_rng(seed)
+    keys0 = np.unique(rng.integers(0, 2000, 300).astype(np.int32))
+    vals0 = rng.integers(0, 1 << 20, keys0.shape[0]).astype(np.int32)
+    return rng, keys0, vals0
+
+
+def donation_build(keys0, vals0):
+    return build(DONATION_CFG, jnp.asarray(keys0), jnp.asarray(vals0))
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_dispatcher_leaves_the_callers_index_intact(depth):
+    """Two dispatchers built in turn from one index (the benchmark's
+    warm-up then serving pattern) both answer exactly as the oracle, and
+    the caller's index still reads as a fresh build afterwards."""
+    from test_query_pipeline import final_pairs
+
+    rng, keys0, vals0 = donation_seed()
+    idx = donation_build(keys0, vals0)
+    ops, keys, keys2, vals = mixed_stream(900, rng)
+    # searches: an arrival-order replay (RANGE is a no-op to the oracle);
+    # ranges: replay_windows checks each against the pre-window oracle
+    want = RefIndex.build(keys0, vals0).execute(ops, keys, vals)
+    runs = []
+    for _ in range(2):
+        ref = RefIndex.build(keys0, vals0)
+        disp = Dispatcher(idx, depth=depth, max_span=4096,
+                          clock=lambda: 0.0)
+        points, ranges, n_checked = replay_windows(
+            disp, Collector(WindowConfig(batch=64)), ops, keys, keys2,
+            vals, ref)
+        assert n_checked > 100
+        for i in np.nonzero(ops == SEARCH)[0]:
+            found, val = points[i]
+            assert (val if found else None) == want[i], i
+        assert final_pairs(disp.index) == ref.data
+        runs.append((points, ranges))
+    assert runs[0] == runs[1]
+    fresh = donation_build(keys0, vals0)
+    for a, b in zip(jax.tree_util.tree_leaves(idx),
+                    jax.tree_util.tree_leaves(fresh)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("breaker", [False, True])
+def test_step_donates_unless_the_breaker_is_armed(breaker):
+    """``pi.dispatch.donated`` / ``pi.dispatch.retained`` count the windows
+    each step program served: every window donates while the breaker is
+    disarmed, none while it is armed (the rollback keeps the old state)."""
+    from repro.pipeline.metrics import clear_span_table, span_table
+
+    rng, keys0, vals0 = donation_seed()
+    ops, keys, keys2, vals = mixed_stream(400, rng)
+    disp = Dispatcher(donation_build(keys0, vals0), depth=1, max_span=4096,
+                      overload=OverloadConfig() if breaker else None,
+                      clock=lambda: 0.0)
+    clear_span_table()
+    replay_windows(disp, Collector(WindowConfig(batch=64)), ops, keys,
+                   keys2, vals, RefIndex.build(keys0, vals0))
+    table = span_table()
+    windows = table["pi.dispatch.launch"][0]
+    donated = table.get("pi.dispatch.donated", (0,))[0]
+    retained = table.get("pi.dispatch.retained", (0,))[0]
+    assert windows > 3
+    assert (donated, retained) == ((0, windows) if breaker
+                                   else (windows, 0))
+
+
+def test_donated_step_aliases_the_index_leaves():
+    """The donating step updates ``vals`` and ``tomb`` in place (an
+    input→output alias); the retaining step aliases nothing."""
+    import re
+
+    from repro.pipeline.dispatcher import _step_single, _step_single_donated
+
+    _, keys0, vals0 = donation_seed()
+    idx = donation_build(keys0, vals0)
+    lanes = jnp.zeros((64,), jnp.int32)
+
+    def aliased_params(program):
+        text = program.lower(idx, lanes, lanes, lanes).compile().as_text()
+        m = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+        return ({int(p) for p in re.findall(r"\((\d+), \{\}", m.group(1))}
+                if m else set())
+
+    leaves = jax.tree_util.tree_leaves(idx)
+    want = {i for i, leaf in enumerate(leaves)
+            if leaf is idx.vals or leaf is idx.tomb}
+    assert len(want) == 2
+    assert want <= aliased_params(_step_single_donated)
+    assert aliased_params(_step_single) == set()
+
+
 def test_pre_window_semantics_writes_in_same_window_invisible():
     """A RANGE sealed into the same window as a covering INSERT must NOT
     see it — every range observes the state at the window boundary."""

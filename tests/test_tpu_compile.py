@@ -13,6 +13,7 @@ cache is off around these compiles — an entry written for a described
 chip cannot be read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import PIConfig, distributed as dist, index as pi
-from repro.pipeline.dispatcher import _step_single
+from repro.pipeline.dispatcher import _step_single, _step_single_donated
 from repro.pipeline.ranges import _sharded_range_executor, execute_ranges
 
 BATCH = 8192
@@ -77,11 +78,18 @@ def lanes(sharding):
     return jax.ShapeDtypeStruct((BATCH,), jnp.int32, sharding=sharding)
 
 
-def test_step_single_compiles(one_chip):
-    compiled = _step_single.lower(index_shapes(ONE_CHIP, one_chip),
-                                  lanes(one_chip), lanes(one_chip),
-                                  lanes(one_chip)).compile()
-    assert "tpu_custom_call" not in compiled.as_text()  # the xla backend
+@pytest.mark.parametrize("donated", [False, True])
+def test_step_single_compiles(one_chip, donated):
+    program = _step_single_donated if donated else _step_single
+    compiled = program.lower(index_shapes(ONE_CHIP, one_chip),
+                             lanes(one_chip), lanes(one_chip),
+                             lanes(one_chip)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text  # the xla backend
+    if donated:
+        # the index is updated in place: no capacity-sized copy is left
+        cap = rf"\[{ONE_CHIP.capacity}\]"
+        assert not re.search(rf"= \w+{cap}\S* copy\(", text)
 
 
 def test_execute_ranges_compiles(one_chip):

@@ -33,8 +33,9 @@ REBUILD_SCOPES = {"pi.rebuild.incremental", "pi.rebuild.repack"}
 RANGE_SCOPES = {"pi.ranges.tables", "pi.ranges.walk", "pi.ranges.pending"}
 HOST_SPANS = {"pi.collect.offer", "pi.collect.take", "pi.collect.seal",
               "pi.wal.append", "pi.wal.fsync", "pi.dispatch.ranges",
-              "pi.dispatch.launch", "pi.dispatch.snapshot",
-              "pi.dispatch.wait", "pi.dispatch.result", "pi.ckpt.save"}
+              "pi.dispatch.launch", "pi.dispatch.donated",
+              "pi.dispatch.snapshot", "pi.dispatch.wait",
+              "pi.dispatch.result", "pi.ckpt.save"}
 
 
 def tiny_index():
@@ -181,6 +182,7 @@ def test_host_spans_land_in_the_trace_nested(traced_run):
     assert inside(evs, "pi.wal.append", {"pi.collect.seal"})
     assert inside(evs, "pi.collect.seal",
                   {"pi.collect.offer", "pi.collect.take"})
+    assert inside(evs, "pi.dispatch.donated", {"pi.dispatch.launch"})
 
 
 def test_span_table_counts_what_the_trace_holds(traced_run):
@@ -195,6 +197,7 @@ def test_span_table_counts_what_the_trace_holds(traced_run):
     assert table["pi.wal.append"][0] == windows
     assert table["pi.dispatch.wait"][0] == windows
     assert table["pi.dispatch.launch"][0] == windows
+    assert table["pi.dispatch.donated"][0] == windows
 
 
 def test_span_times_and_nests_without_a_profiler():
